@@ -14,6 +14,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
+import os
+import pathlib
 import time
 from typing import Dict, List
 
@@ -28,6 +31,32 @@ SEEDS = (0, 1, 2)            # paper: three seeds, report mean/min/max
 SAMPLES = 600                # per device (paper: 5000; scaled for CPU)
 DEVICE_COUNTS = (2, 5, 10, 25, 50, 100)
 MESH = None                  # set by run.py --mesh-shape; None = one chip
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+GOLDEN_FIGURES = REPO_ROOT / "tests" / "golden" / "figures.json"
+
+# golden-figure drift tolerances, per metric family: just above the
+# drift observed at the event-jump switchover (sr <= 4.31 on a knife-
+# edge per-tier slice under overload, overall sr <= 1.6; acc <= 0.0024;
+# throughput <= 0.5% relative)
+GOLDEN_TOL = {
+    "sr": 5.0,         # absolute, for 0-100 sr-family metrics
+    "acc": 0.01,       # absolute, for [0,1] accuracy-family metrics
+    "thr": 0.03,       # relative, for throughput (samples/s)
+    "corr": 0.5,       # absolute, for the fig19 threshold/activity corr
+    "switches": 1.0,   # absolute, for fig17 model-switch counts
+}
+
+
+def use_compile_cache() -> None:
+    """Persistent compile cache for an entry point: where
+    ``JAX_COMPILATION_CACHE_DIR`` says if it is set (JAX reads it
+    itself), else the fixed ``<repo>/.jax_cache`` — a fixed path, since
+    the directory is part of what a later run must find again."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
 
 
 def sweep(specs, streams, dev_latency, slo, servers, **kw):
@@ -148,3 +177,45 @@ def capture_figure_rows(settings: Dict) -> Dict[str, Dict[str, float]]:
         return rows
     finally:
         SEEDS, SAMPLES, DEVICE_COUNTS = old
+
+
+def _golden_family(key: str) -> str:
+    if "corr" in key:
+        return "corr"
+    if key.startswith("acc"):
+        return "acc"
+    if key.startswith("switches"):
+        return "switches"
+    if key.startswith("thr"):
+        return "thr"
+    return "sr"      # sr, sr_min, sr_max, sr_<tier>
+
+
+def golden_drift(current: Dict[str, Dict[str, float]],
+                 golden: Dict[str, Dict[str, float]]) -> List[str]:
+    """Every metric of ``current`` (``capture_figure_rows``' output)
+    that drifted from ``golden`` beyond ``GOLDEN_TOL``; [] when none."""
+    if set(current) != set(golden):
+        return ["figure row set changed; re-capture "
+                "tests/golden/figures.json"]
+    failures = []
+    for name, gm in golden.items():
+        cm = current[name]
+        for key, gv in gm.items():
+            if key not in cm:
+                failures.append(f"{name}: {key} missing")
+                continue
+            cv = cm[key]
+            if math.isnan(gv) or math.isnan(cv):
+                if math.isnan(gv) != math.isnan(cv):
+                    failures.append(f"{name}: {key} nan mismatch "
+                                    f"golden={gv} now={cv}")
+                continue
+            fam = _golden_family(key)
+            tol = GOLDEN_TOL[fam]
+            if fam == "thr":
+                tol *= max(abs(gv), 1e-9)
+            if abs(cv - gv) > tol:
+                failures.append(
+                    f"{name}: {key} golden={gv:.4f} now={cv:.4f}")
+    return failures

@@ -56,6 +56,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import common
+    common.use_compile_cache()
     if args.quick:
         common.SEEDS = (0,)
         common.SAMPLES = 200

@@ -15,27 +15,40 @@ from __future__ import annotations
 from typing import Dict, Iterator, List, Set, Tuple
 
 import jax
-
-try:  # jax >= 0.4.x keeps these in jax.core / jax.extend
-    from jax.core import ClosedJaxpr, Jaxpr, Literal, Var  # type: ignore
-except ImportError:  # pragma: no cover - version drift guard
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
 
 
-def unwrap_pjit(jaxpr: Jaxpr) -> Jaxpr:
-    """``make_jaxpr`` of a jitted function yields one pjit eqn wrapping
-    the real program; descend to it (repeatedly, for nested wrappers
-    with matching arity)."""
-    while (len(jaxpr.eqns) == 1
-           and jaxpr.eqns[0].primitive.name == "pjit"
-           and list(jaxpr.eqns[0].invars) == list(jaxpr.invars)
-           and list(jaxpr.eqns[0].outvars) == list(jaxpr.outvars)):
-        jaxpr = jaxpr.eqns[0].params["jaxpr"].jaxpr
+def unwrap_jit(jaxpr: Jaxpr) -> Jaxpr:
+    """``make_jaxpr`` of a jitted function yields one ``jit`` eqn
+    wrapping the real program; descend to it (repeatedly, for nested
+    wrappers with matching arity).
+
+    Any other primitive in that wrapper position (a ``remat2``, a
+    ``shard_map``, a renamed ``jit``) raises: the rules would otherwise
+    see the whole program as one opaque eqn whose outputs depend on
+    every input, and pass vacuously.
+    """
+    while len(jaxpr.eqns) == 1:
+        eqn = jaxpr.eqns[0]
+        inner = eqn.params.get("jaxpr")
+        inner = inner.jaxpr if isinstance(inner, ClosedJaxpr) else inner
+        if not (isinstance(inner, Jaxpr)
+                and list(eqn.invars) == list(jaxpr.invars)
+                and list(eqn.outvars) == list(jaxpr.outvars)
+                and len(inner.invars) == len(eqn.invars)
+                and len(inner.outvars) == len(eqn.outvars)):
+            break
+        if eqn.primitive.name != "jit":
+            raise ValueError(
+                f"unknown wrapper primitive {eqn.primitive.name!r} around "
+                f"the traced program; jaxpr_tools.unwrap_jit must learn "
+                f"to descend through it")
+        jaxpr = inner
     return jaxpr
 
 
 def sub_jaxprs(eqn) -> Iterator[Jaxpr]:
-    """All jaxprs referenced by an eqn's params (cond/while/scan/pjit
+    """All jaxprs referenced by an eqn's params (cond/while/scan/jit
     branches, bodies, ...)."""
     for val in eqn.params.values():
         vals = val if isinstance(val, (list, tuple)) else (val,)

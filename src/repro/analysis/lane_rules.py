@@ -31,10 +31,7 @@ import jax
 from repro.analysis.findings import Finding, Severity
 from repro.analysis import jaxpr_tools as jt
 
-try:
-    from jax.core import Literal, Var  # type: ignore
-except ImportError:  # pragma: no cover - version drift guard
-    from jax.extend.core import Literal, Var  # type: ignore
+from jax.extend.core import Literal, Var
 
 FAMILY = "lane-mask"
 
@@ -72,7 +69,7 @@ def check_lane_entry(entry: LaneEntry) -> List[Finding]:
 
 def _body_jaxpr(entry: LaneEntry):
     closed = jax.make_jaxpr(entry.body)(entry.st0)
-    jaxpr = jt.unwrap_pjit(closed.jaxpr)
+    jaxpr = jt.unwrap_jit(closed.jaxpr)
     paths = jt.leaf_paths(entry.st0)
     if len(jaxpr.invars) != len(paths) or len(jaxpr.outvars) != len(paths):
         raise ValueError(

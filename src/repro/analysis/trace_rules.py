@@ -60,11 +60,11 @@ class StaticKeyEntry:
 def _trace(entry: TraceEntry, x64: bool):
     fn, args, kwargs = entry.build()
     if x64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(fn)(*args, **kwargs)
     else:
         closed = jax.make_jaxpr(fn)(*args, **kwargs)
-    return jt.unwrap_pjit(closed.jaxpr), args, kwargs
+    return jt.unwrap_jit(closed.jaxpr), args, kwargs
 
 
 def _entry_path(entry: TraceEntry) -> str:

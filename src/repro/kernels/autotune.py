@@ -12,8 +12,10 @@ Each candidate is sanity-checked two ways before it can win:
 * **numerics** — its outputs must match the ``ref`` dispatch on the
   sweep input (a mistiled kernel loses to the gate, not to luck);
 * **roofline** — the measured us/sample is reported against the memory
-  bound ``B*V*4 / HBM_BW`` from ``roofline/analysis.py``. On a CPU host
-  the interpret-mode kernel sits far above the TPU bound (that is
+  bound ``B*V*4 / hbm_bw``, with the attached chip's published HBM
+  bandwidth from ``roofline/analysis.PEAKS`` (a TPU whose kind is not in
+  the table raises). On a CPU host the interpret-mode kernel is held
+  against the v5e target's bound and sits far above it (that is
   expected and recorded, not enforced); on a TPU backend a candidate
   slower than ``max_over_bound`` x the bound is rejected as mistiled.
 
@@ -32,7 +34,7 @@ import numpy as np
 
 from repro.kernels import ops
 from repro.kernels.timing import time_blocked
-from repro.roofline.analysis import HBM_BW
+from repro.roofline.analysis import TARGET_KIND, peaks
 
 CANDIDATE_BB = (4, 8, 16, 32)
 CANDIDATE_BV = (128, 256, 512, 1024)
@@ -50,7 +52,9 @@ NUMERIC_ATOL = 2e-3
 
 def roofline_floor_s(b: int, v: int) -> float:
     """Memory-bound floor: the kernel must at least read the logits."""
-    return (b * v * 4) / HBM_BW
+    dev = jax.devices()[0]
+    kind = dev.device_kind if dev.platform == "tpu" else TARGET_KIND
+    return (b * v * 4) / peaks(kind).hbm_bw
 
 
 def sweep(b: int = SWEEP_B, v: int = SWEEP_V, *, mode: str = None,

@@ -48,14 +48,15 @@ def _bvsb_kernel(logits_ref, bvsb_ref, top1_ref, m1_s, m2_s, z_s, idx_s):
         z_s[...] = jnp.zeros_like(z_s)
         idx_s[...] = jnp.zeros_like(idx_s)
 
+    # every per-row quantity is a (BB, 1) column: Mosaic only takes a
+    # rank-1 block that spans the whole array or is a multiple of 128
     x = logits_ref[...].astype(jnp.float32)            # (BB, BV)
-    tile_m1 = jnp.max(x, axis=1)
-    tile_arg = jnp.argmax(x, axis=1).astype(jnp.int32)
+    tile_m1 = jnp.max(x, axis=1, keepdims=True)
+    tile_arg = jnp.argmax(x, axis=1, keepdims=True).astype(jnp.int32)
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    masked = jnp.where(cols == tile_arg[:, None],
-                       jnp.float32(-jnp.inf), x)
-    tile_m2 = jnp.max(masked, axis=1)
-    tile_z = jnp.sum(jnp.exp(x - tile_m1[:, None]), axis=1)
+    masked = jnp.where(cols == tile_arg, jnp.float32(-jnp.inf), x)
+    tile_m2 = jnp.max(masked, axis=1, keepdims=True)
+    tile_z = jnp.sum(jnp.exp(x - tile_m1), axis=1, keepdims=True)
 
     m1_old, m2_old = m1_s[...], m2_s[...]
     z_old, idx_old = z_s[...], idx_s[...]
@@ -106,16 +107,16 @@ def bvsb(logits, *, interpret=False, bb=None, bv=None):
         _bvsb_kernel,
         grid=(bp // bb, vp // bv),
         in_specs=[pl.BlockSpec((bb, bv), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((bb,), lambda i, j: (i,)),
-                   pl.BlockSpec((bb,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((bp,), jnp.float32),
-                   jax.ShapeDtypeStruct((bp,), jnp.int32)],
+        out_specs=[pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
+                   pl.BlockSpec((bb, 1), lambda i, j: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((bp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((bp, 1), jnp.int32)],
         scratch_shapes=[
-            pltpu.VMEM((bb,), jnp.float32),
-            pltpu.VMEM((bb,), jnp.float32),
-            pltpu.VMEM((bb,), jnp.float32),
-            pltpu.VMEM((bb,), jnp.int32),
+            pltpu.VMEM((bb, 1), jnp.float32),
+            pltpu.VMEM((bb, 1), jnp.float32),
+            pltpu.VMEM((bb, 1), jnp.float32),
+            pltpu.VMEM((bb, 1), jnp.int32),
         ],
         interpret=interpret,
     )(x)
-    return (out[:b], top1[:b]) if (padb or padv) else (out, top1)
+    return out[:b, 0], top1[:b, 0]

@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch import shardings as sh
 from repro.launch.mesh import batch_axes_of
-from repro.models.common import MeshContext, shard_map
+from repro.models.common import MeshContext
 from repro.models.model import IGNORE, Model
 from repro.training import optimizer as opt
 
@@ -61,7 +61,7 @@ def vocab_parallel_ce(hidden, table, labels, mesh, batch_axes, vocab_size):
             den = jax.lax.psum(den, batch_axes)
         return num / jnp.maximum(den, 1.0)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ba, None, None), P(MODEL, None), P(ba, None)),
         out_specs=P(), check_vma=False)(hidden, table, labels)
@@ -93,7 +93,7 @@ def vocab_parallel_bvsb(hidden, table, mesh, batch_axes, vocab_size):
         bvsb = (1.0 - jnp.exp(m2 - m1)) / z
         return bvsb, top1
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ba, None, None), P(MODEL, None)),
         out_specs=(P(ba), P(ba)), check_vma=False)(hidden, table)

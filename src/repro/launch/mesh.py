@@ -6,25 +6,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count before first jax init).
 """
 from __future__ import annotations
 
-import functools
-import inspect
-
 import jax
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    @functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        """Compat: older jax calls the replication check ``check_rep``."""
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

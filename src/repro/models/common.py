@@ -12,10 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# the version-compat shim lives with the mesh helpers; re-exported here
-# for the model stack (moe.py, distributed launch)
-from repro.launch.mesh import shard_map  # noqa: F401
-
 
 # ---------------------------------------------------------------------------
 # mesh / sharding context threaded through the model

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import KeyGen, MeshContext, dense_init, shard_map
+from repro.models.common import KeyGen, MeshContext, dense_init
 
 CAPACITY_FACTOR = 1.25
 
@@ -144,7 +144,7 @@ def moe_apply(params, x, cfg, mctx: MeshContext, *, act=jax.nn.silu,
         ma = mctx.model_axis
         ba = mctx.batch_axes if mctx.batch_axes else None
         x_spec = P(ba, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=mctx.mesh,
             in_specs=(x_spec, P(None, None), P(ma, None, None),
                       P(ma, None, None), P(ma, None, None)),

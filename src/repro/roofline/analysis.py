@@ -19,9 +19,34 @@ from typing import Dict, Optional
 from repro.configs.base import ArchConfig, InputShape
 from repro.roofline.hlo import HloStats
 
-PEAK_FLOPS = 197e12        # bf16 / chip (v5e)
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM bytes/s per chip
+    ici_bw: float     # inter-chip bytes/s per link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is the TPU v5e — Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect over 4 links (50 GB/s per link).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+# the chip the dry-run roofline is projected onto
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; a kind not in the table is
+    an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            f"them to roofline.analysis.PEAKS with their source") from None
 
 
 @dataclasses.dataclass
@@ -65,9 +90,10 @@ def compute_roofline(cfg: ArchConfig, shape: InputShape, stats: HloStats,
     # memory: dot operand traffic is the dominant HBM term; add param reads
     # once (weights streamed from HBM each step even when dots fuse)
     mem_bytes_dev = max(stats.dot_bytes, param_bytes_per_device)
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = mem_bytes_dev / HBM_BW
-    coll_s = stats.collective_bytes / ICI_BW
+    chip = peaks(TARGET_KIND)
+    compute_s = flops_dev / chip.flops
+    memory_s = mem_bytes_dev / chip.hbm_bw
+    coll_s = stats.collective_bytes / chip.ici_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": coll_s}
     dominant = max(terms, key=terms.get)

@@ -81,6 +81,20 @@ EV_ONLINE = 3   # device back online (sample-indexed offline mode)
 EV_SRV = 4      # server batch finish
 EV_WINDOW = 5   # SLO window boundary
 
+# documented jaxsim-vs-this-sim tolerances per scheduler (totals, then
+# per-window trajectories): float32 vs float64 event times and the
+# launch-vs-finish window attribution make the two differ by design;
+# static takes identical decisions and is held tight. Conservation
+# (completed counts) is exact. See tests/test_differential.py.
+SIM_TOL = {
+    "static": dict(sr=1.0, acc=0.01, fwd=0.01, sr_traj=10.0,
+                   acc_traj=0.05, fwd_traj=0.02),
+    "multitasc": dict(sr=3.0, acc=0.02, fwd=0.05, sr_traj=12.0,
+                      acc_traj=0.07, fwd_traj=0.12),
+    "multitasc++": dict(sr=3.0, acc=0.02, fwd=0.05, sr_traj=12.0,
+                        acc_traj=0.07, fwd_traj=0.12),
+}
+
 
 @dataclasses.dataclass
 class DeviceRuntime:

@@ -282,8 +282,7 @@ from repro.configs.cascade_tiers import BATCH_LADDER, ServerProfile
 from repro.core import multitasc as mt
 from repro.core import multitascpp as mtpp
 from repro.core import switching
-from repro.launch.mesh import (batch_axes_of, device_axis_of, n_lanes,
-                               shard_map)
+from repro.launch.mesh import batch_axes_of, device_axis_of, n_lanes
 
 MAX_POP = 64
 N_BUCKET = 128          # device axis pads up to a multiple of this
@@ -810,9 +809,9 @@ def _make_core_sharded(static: JaxSimStatic, mesh):
     rep = jax.sharding.PartitionSpec()
     # check_vma=False: the body is collective-free (each shard loops over
     # its own lanes), and the replication checker has no rule for while
-    sharded = shard_map(functools.partial(_run_core_lanes, static),
-                        mesh=mesh, in_specs=(bspec, rep) + (bspec,) * 12,
-                        out_specs=bspec, check_vma=False)
+    sharded = jax.shard_map(functools.partial(_run_core_lanes, static),
+                            mesh=mesh, in_specs=(bspec, rep) + (bspec,) * 12,
+                            out_specs=bspec, check_vma=False)
     return jax.jit(sharded, donate_argnums=(2, 3, 4, 5))
 
 
@@ -1814,6 +1813,17 @@ def _run_core_device(static, k, axis, params, srv, conf, cl, ch, arrive,
 # else replicated (identical on every shard by construction)
 _DEVICE_OUT_SHARDED = ("per_device_sr", "per_device_acc", "final_thresh")
 
+# run_device_sharded's contract against the local segmented engine:
+# fleet dynamics bitwise (integer totals, elementwise per-device floats,
+# integer trace rows); psum-of-partials float aggregates may differ in
+# the last ulp (their reduction order differs from the flat sum)
+SHARDED_EXACT_KEYS = ("completed", "queue_left", "queue_peak", "sr",
+                      "throughput", "forwarded_frac", "per_device_sr",
+                      "per_device_acc", "final_thresh", "n_events")
+SHARDED_EXACT_TRACES = ("active", "server_idx", "fwd")
+SHARDED_ULP_KEYS = ("accuracy",)
+SHARDED_ULP_TRACES = ("thresh", "sr", "acc")
+
 
 @functools.lru_cache(maxsize=64)
 def _make_core_device(static: JaxSimStatic, mesh):
@@ -1835,10 +1845,10 @@ def _make_core_device(static: JaxSimStatic, mesh):
         "sr", "accuracy", "throughput", "forwarded_frac", "completed",
         "queue_left", "queue_peak", "n_events")})
     out_specs["traces"] = {key: rep for key in TRACE_KEYS}
-    sharded = shard_map(functools.partial(_run_core_device, static, k,
-                                          axis),
-                        mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                        check_vma=False)
+    sharded = jax.shard_map(functools.partial(_run_core_device, static, k,
+                                              axis),
+                            mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return jax.jit(sharded, donate_argnums=(2, 3, 4, 5))
 
 

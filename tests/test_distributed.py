@@ -117,8 +117,9 @@ HLO_FORMAT_FIXTURES = {
     # add a version ONLY after vetting rhlo.diagnose() against its real
     # dumps (the canary test below then guards it); pre-registering
     # future versions would defeat the vet-before-trust design
-    (0, 4): dict(inline_operand_types=True),   # operand types inline
-                                               # since 0.4.37
+    (0, 9): dict(inline_operand_types=True),   # operand types inline
+                                               # since 0.4.37; vetted on
+                                               # 0.9.0's CPU dumps
 }
 
 
@@ -239,6 +240,26 @@ def test_roofline_terms_and_dominance():
     assert r.dominant == "compute"
     assert r.model_flops == pytest.approx(
         6 * cfg.active_param_count() * 256 * 4096)
+
+
+def test_peaks_keyed_by_device_kind(monkeypatch):
+    """v5e's published peaks are in the table; a TPU kind that is not
+    raises — in the table lookup and in the autotuner's roofline floor —
+    instead of being timed against v5e's numbers."""
+    from repro.kernels import autotune
+    from repro.roofline import analysis
+
+    v5e = analysis.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        analysis.peaks("TPU v9 imaginary")
+
+    class _Dev:
+        platform, device_kind = "tpu", "TPU v9 imaginary"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
+    with pytest.raises(ValueError, match="no published peaks"):
+        autotune.roofline_floor_s(64, 2048)
 
 
 def test_model_flops_moe_uses_active_params():

@@ -13,40 +13,21 @@ behaviour-preserving end to end, not just on the unit level.
 
 Observed drift at the event-jump switchover: sr <= 4.31 (a knife-edge
 per-tier slice under overload; overall sr <= 1.6), acc <= 0.0024,
-throughput <= 0.5% relative — the tolerances below leave modest headroom
-over that. To re-capture after an *intentional* behaviour change (e.g.
-a stream-fixture bump):
+throughput <= 0.5% relative — the tolerances
+(``benchmarks.common.GOLDEN_TOL``) leave modest headroom over that. To
+re-capture after an *intentional* behaviour change (e.g. a
+stream-fixture bump):
 
     PYTHONPATH=src python tools/capture_golden.py
 
 and document why in the commit message.
 """
 import json
-import math
 import pathlib
 
-import numpy as np
 import pytest
 
 GOLDEN = pathlib.Path(__file__).parent / "golden" / "figures.json"
-
-SR_TOL = 5.0        # absolute, for 0-100 sr-family metrics
-ACC_TOL = 0.01      # absolute, for [0,1] accuracy-family metrics
-THR_REL_TOL = 0.03  # relative, for throughput (samples/s)
-CORR_TOL = 0.5      # absolute, for the fig19 threshold/activity corr
-SWITCH_TOL = 1.0    # absolute, for fig17 model-switch counts
-
-
-def _family(key: str) -> str:
-    if "corr" in key:
-        return "corr"
-    if key.startswith("acc"):
-        return "acc"
-    if key.startswith("switches"):
-        return "switches"
-    if key.startswith("thr"):
-        return "thr"
-    return "sr"      # sr, sr_min, sr_max, sr_<tier>
 
 
 @pytest.fixture(scope="module")
@@ -58,36 +39,11 @@ def current_rows():
 
 
 def test_no_drift_vs_golden(current_rows):
+    """Drift beyond ``benchmarks.common.GOLDEN_TOL`` (per metric family)
+    fails; the comparison is shared with chip_smoke.py."""
+    from benchmarks.common import golden_drift
     golden = json.loads(GOLDEN.read_text())["rows"]
-    assert set(current_rows) == set(golden), (
-        "figure row set changed; re-capture tests/golden/figures.json")
-    failures = []
-    for name, gm in golden.items():
-        cm = current_rows[name]
-        for key, gv in gm.items():
-            if key not in cm:
-                failures.append(f"{name}: {key} missing")
-                continue
-            cv = cm[key]
-            if math.isnan(gv) or math.isnan(cv):
-                if math.isnan(gv) != math.isnan(cv):
-                    failures.append(f"{name}: {key} nan mismatch "
-                                    f"golden={gv} now={cv}")
-                continue
-            fam = _family(key)
-            if fam == "thr":
-                ok = abs(cv - gv) <= THR_REL_TOL * max(abs(gv), 1e-9)
-            elif fam == "acc":
-                ok = abs(cv - gv) <= ACC_TOL
-            elif fam == "corr":
-                ok = abs(cv - gv) <= CORR_TOL
-            elif fam == "switches":
-                ok = abs(cv - gv) <= SWITCH_TOL
-            else:
-                ok = abs(cv - gv) <= SR_TOL
-            if not ok:
-                failures.append(
-                    f"{name}: {key} golden={gv:.4f} now={cv:.4f}")
+    failures = golden_drift(current_rows, golden)
     assert not failures, "golden drift:\n" + "\n".join(failures)
 
 

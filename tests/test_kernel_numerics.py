@@ -136,7 +136,7 @@ def test_bvsb_pos_inf_logits_nan_in_both_modes():
         assert np.all(np.isnan(np.asarray(conf))), mode
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(b=st.integers(min_value=1, max_value=8),
        v=st.integers(min_value=2, max_value=200),
        seed=st.integers(min_value=0, max_value=10000),

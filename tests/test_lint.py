@@ -86,6 +86,19 @@ def test_lane_checker_passes_real_body(real_lane_entry):
     assert findings == [], [f.render() for f in findings]
 
 
+def test_unknown_wrapper_primitive_fails_loudly(real_lane_entry):
+    """A body wrapped in a call primitive other than ``jit`` must make
+    the checker raise: read as one opaque eqn, every output would
+    depend on every input and the checks would pass vacuously."""
+    import jax
+
+    wrapped = dataclasses.replace(real_lane_entry,
+                                  body=jax.checkpoint(real_lane_entry.body),
+                                  name="remat-wrapped-lane")
+    with pytest.raises(ValueError, match="unknown wrapper primitive"):
+        lane_rules.check_lane_entry(wrapped)
+
+
 def test_lane_checker_fails_mutated_body(real_lane_entry):
     """A one-line mutation — a carry leaf overwritten with real data
     that carries no active-lane dependence — must be caught."""

@@ -96,3 +96,28 @@ def test_bench_schema_constants_in_lockstep():
     assert baseline.get("_schema") == run_mod.BENCH_SCHEMA, (
         "committed BENCH_jaxsim.json was captured under a different "
         "schema; re-run benchmarks/run.py --quick --json")
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir_rule(monkeypatch, tmp_path, env_dir):
+    """Entry points leave JAX_COMPILATION_CACHE_DIR to JAX when it is set
+    and otherwise pin the cache to the fixed <repo>/.jax_cache — never a
+    temporary or per-process path, which a later run could not find."""
+    from benchmarks import common
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    try:
+        common.use_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    if env_dir is None:
+        assert got == str(common.REPO_ROOT / ".jax_cache")
+    else:
+        assert got is None

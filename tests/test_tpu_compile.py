@@ -51,6 +51,7 @@ def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
+    # the TPU library otherwise writes its logs outside the checkout
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)

@@ -363,7 +363,7 @@ class JaxSimStatic:
 class SweepStats:
     """Process-wide counters for benchmark/regression accounting."""
     cores_built: int = 0        # distinct (static,) lane cores traced
-    backend_compiles: int = 0   # XLA backend_compile events (all of jax)
+    backend_compiles: int = 0   # XLA compiles (all of jax), cache loads not
     points: int = 0             # sweep points simulated
     events: int = 0             # event-loop iterations across all points
     sharded_points: int = 0     # points executed by a >1-lane sharded core
@@ -373,6 +373,10 @@ class SweepStats:
 stats = SweepStats()
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# JAX times a load from the persistent compilation cache as a backend
+# compile too, and records this event inside that timing: each hit takes
+# back the compile event that follows it
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def _on_jax_event(event: str, duration: float, **_) -> None:
@@ -380,8 +384,14 @@ def _on_jax_event(event: str, duration: float, **_) -> None:
         stats.backend_compiles += 1
 
 
+def _on_jax_cache_hit(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        stats.backend_compiles -= 1
+
+
 try:  # compile counting is best-effort: cores_built remains the fallback
     jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+    jax.monitoring.register_event_listener(_on_jax_cache_hit)
 except Exception:  # pragma: no cover - monitoring API unavailable
     pass
 
@@ -677,6 +687,15 @@ def _prepare(specs, streams, dev_latency, slo, servers, tier_ids, c_upper,
     return static, params, srv, arrays, b, n
 
 
+def _host_span(name: str):
+    """A host span named ``name`` on the profiler's clock, so a trace can
+    put the device's idle time down to the sweep phase the host was in:
+    ``jaxsim.prepare`` (``_prepare``), ``jaxsim.transfer`` (placing the
+    inputs, waited for) and ``jaxsim.execute`` (dispatch, the core run and
+    the fetch of its outputs). With the profiler off it is one check."""
+    return jax.profiler.TraceAnnotation(name)
+
+
 def _finalize(out, b, n):
     out = dict(out)
     for k in ("per_device_sr", "per_device_acc", "final_thresh"):
@@ -715,10 +734,11 @@ def run_sweep(specs: Union[JaxSimSpec, Sequence[JaxSimSpec]], streams,
     across points without recompiling; only static structure forces a
     new executable. Stream buffers are donated to the computation.
     """
-    static, params, srv, arrays, b, n = _prepare(
-        specs, streams, dev_latency, slo, servers, tier_ids, c_upper,
-        offline_start, offline_for, join_t, leave_t,
-        frontier_seg=frontier_seg)
+    with _host_span("jaxsim.prepare"):
+        static, params, srv, arrays, b, n = _prepare(
+            specs, streams, dev_latency, slo, servers, tier_ids, c_upper,
+            offline_start, offline_for, join_t, leave_t,
+            frontier_seg=frontier_seg)
     return _run_local(static, params, srv, arrays, b, n)
 
 
@@ -727,18 +747,21 @@ def _run_local(static, params, srv, arrays, b, n):
     # serial bypass is gone: without a vmapped while_loop there is no
     # whole-carry select for a single lane to dodge — see
     # benchmarks/fig11_lanes.py for the measured B=1 parity)
-    core = _make_core(static)
-    args = (jax.device_put(params), jax.device_put(srv),
-            *(jax.device_put(a) for a in arrays))
-    with warnings.catch_warnings():
+    with _host_span("jaxsim.transfer"):
+        # waited for, so that the span ends with the inputs on the device
+        # (the core waits for them before its first op anyway)
+        args = jax.block_until_ready(
+            (jax.device_put(params), jax.device_put(srv),
+             *(jax.device_put(a) for a in arrays)))
+    with _host_span("jaxsim.execute"), warnings.catch_warnings():
         # scoped to this jit call only: the *local* path may legitimately
         # fail to alias donated stream buffers on some backends (the copy
         # is what would have happened anyway); the sharded path must not
         # swallow donation regressions, so it runs unfiltered
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
-        out = core(*args)
-    return _finalize(out, b, n)
+        out = _make_core(static)(*args)
+        return _finalize(out, b, n)
 
 
 def run_sweep_sharded(specs: Union[JaxSimSpec, Sequence[JaxSimSpec]],
@@ -767,29 +790,32 @@ def run_sweep_sharded(specs: Union[JaxSimSpec, Sequence[JaxSimSpec]],
                          offline_start=offline_start,
                          offline_for=offline_for, join_t=join_t,
                          leave_t=leave_t, frontier_seg=frontier_seg)
-    static, params, srv, arrays, b, n = _prepare(
-        specs, streams, dev_latency, slo, servers, tier_ids, c_upper,
-        offline_start, offline_for, join_t, leave_t,
-        frontier_seg=frontier_seg)
+    with _host_span("jaxsim.prepare"):
+        static, params, srv, arrays, b, n = _prepare(
+            specs, streams, dev_latency, slo, servers, tier_ids, c_upper,
+            offline_start, offline_for, join_t, leave_t,
+            frontier_seg=frontier_seg)
+        b_pad = -(-b // lanes) * lanes
+        if b > 1 and b_pad != b:
+            def pad(x):
+                return np.concatenate(
+                    [x, np.repeat(x[:1], b_pad - b, axis=0)], axis=0)
+            params = {k: pad(v) for k, v in params.items()}
+            arrays = tuple(pad(a) for a in arrays)
     if b == 1:
         return _run_local(static, params, srv, arrays, b, n)
-    b_pad = -(-b // lanes) * lanes
-    if b_pad != b:
-        def pad(x):
-            return np.concatenate(
-                [x, np.repeat(x[:1], b_pad - b, axis=0)], axis=0)
-        params = {k: pad(v) for k, v in params.items()}
-        arrays = tuple(pad(a) for a in arrays)
     bspec = jax.sharding.PartitionSpec(tuple(batch_axes_of(mesh)))
     batch_sh = jax.sharding.NamedSharding(mesh, bspec)
     rep_sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-    core = _make_core_sharded(static, mesh)
-    out = core(jax.device_put(params, batch_sh),
-               jax.device_put(srv, rep_sh),
-               *(jax.device_put(a, batch_sh) for a in arrays))
-    out = jax.tree.map(lambda x: np.asarray(x)[:b], out)
-    stats.sharded_points += b
-    return _finalize(out, b, n)
+    with _host_span("jaxsim.transfer"):
+        args = jax.block_until_ready(
+            (jax.device_put(params, batch_sh), jax.device_put(srv, rep_sh),
+             *(jax.device_put(a, batch_sh) for a in arrays)))
+    with _host_span("jaxsim.execute"):
+        out = _make_core_sharded(static, mesh)(*args)
+        out = jax.tree.map(lambda x: np.asarray(x)[:b], out)
+        stats.sharded_points += b
+        return _finalize(out, b, n)
 
 
 @functools.lru_cache(maxsize=256)
@@ -909,7 +935,8 @@ def _seg_phases(static: JaxSimStatic):
             "samp": jnp.where(fwd_mask, cj, 0).astype(jnp.int32),
             "fwd": fwd_mask.astype(jnp.int32),
         }
-        seg_min_new = jnp.min(jnp.where(cursor2 < s, dn2, jnp.inf))
+        with jax.named_scope("jaxsim.frontier"):
+            seg_min_new = jnp.min(jnp.where(cursor2 < s, dn2, jnp.inf))
         return seg_upd, append, seg_min_new, jnp.any(comp_local)
 
     def apply_append(q_start, q_dev, q_samp, tail, append):
@@ -967,6 +994,7 @@ def _engine_fns(static: JaxSimStatic):
         offline = (t_complete >= c["off_start"]) & (t_complete < off_end)
         return jnp.where(offline, off_end, t_complete)
 
+    @jax.named_scope("jaxsim.frontier")
     def next_event_t(st):
         # next device completion; padded / finished devices sit at +inf.
         # Segmented frontier: the completion min reduces over the
@@ -1052,101 +1080,110 @@ def _engine_fns(static: JaxSimStatic):
         t = st["frontier"]
 
         # --- device completions at exactly this instant -------------------
-        due = (st["dev_next"] <= t) & (st["cursor"] < s) & go
-        # a would-be completion at or past leave_t is the lazy departure
-        # event: the sample (and the rest of the stream) is dropped, the
-        # device goes inert — samples already forwarded to the server are
-        # unaffected and finish normally
-        departs = due & (st["dev_next"] >= c["leave_t"])
-        done = due & ~departs
-        cj = jnp.clip(st["cursor"], 0, s - 1)
-        conf_j = conf[jnp.arange(n, dtype=jnp.int32), cj]
-        local = conf_j >= st["thresh"]          # Eq. 3
-        comp_local = done & local
-        met_local = dev_latency <= slo
-        win_met = st["win_met"] + (comp_local & met_local)
-        win_total = st["win_total"] + comp_local
-        tot_met = st["tot_met"] + (comp_local & met_local)
-        tot = st["tot"] + comp_local
-        correct = st["correct"] + comp_local * cl[jnp.arange(n, dtype=jnp.int32), cj]
+        with jax.named_scope("jaxsim.devices"):
+            due = (st["dev_next"] <= t) & (st["cursor"] < s) & go
+            # a would-be completion at or past leave_t is the lazy
+            # departure event: the sample (and the rest of the stream) is
+            # dropped, the device goes inert — samples already forwarded
+            # to the server are unaffected and finish normally
+            departs = due & (st["dev_next"] >= c["leave_t"])
+            done = due & ~departs
+            cj = jnp.clip(st["cursor"], 0, s - 1)
+            conf_j = conf[jnp.arange(n, dtype=jnp.int32), cj]
+            local = conf_j >= st["thresh"]          # Eq. 3
+            comp_local = done & local
+            met_local = dev_latency <= slo
+            win_met = st["win_met"] + (comp_local & met_local)
+            win_total = st["win_total"] + comp_local
+            tot_met = st["tot_met"] + (comp_local & met_local)
+            tot = st["tot"] + comp_local
+            correct = st["correct"] + comp_local * cl[
+                jnp.arange(n, dtype=jnp.int32), cj]
+            fwd_mask = done & ~local
+            st_fwd = st["fwd"] + fwd_mask
 
-        fwd_mask = done & ~local
-        st_fwd = st["fwd"] + fwd_mask
-        pos = st["tail"] + jnp.cumsum(fwd_mask, dtype=jnp.int32) - 1
-        # non-forwarding rows aim at index cap and are dropped: an
-        # in-ring dummy slot would collide with a REAL append once a
-        # small queue_cap wraps tail past it (duplicate-index scatter,
-        # order-dependent)
-        posm = jnp.where(fwd_mask, pos % cap, cap)
-        q_start = st["q_start"].at[posm].set(
-            st["dev_next"] - dev_latency, mode="drop")
-        q_dev = st["q_dev"].at[posm].set(jnp.arange(n, dtype=jnp.int32),
-                                         mode="drop")
-        q_samp = st["q_samp"].at[posm].set(cj, mode="drop")
-        tail = st["tail"] + jnp.sum(fwd_mask, dtype=jnp.int32)
+            # a departed device's stream counts as exhausted (drained()
+            # and next_event_t both read cursor >= s), so the drain
+            # early-exit fires without its dropped samples ever completing
+            cursor = jnp.where(departs, s, st["cursor"] + done)
+            # next sample starts when the device is free AND it has
+            # arrived (no arrival tensor -> back-to-back, the gather
+            # compiles out)
+            if static.has_arrive:
+                arrive_next = arrive_c[jnp.arange(n, dtype=jnp.int32),
+                                       jnp.clip(cursor, 0, s - 1)]
+                start_next = jnp.maximum(st["dev_next"], arrive_next)
+            else:
+                start_next = st["dev_next"]
+            dev_next = jnp.where(done,
+                                 defer_offline(start_next + dev_latency, c),
+                                 st["dev_next"])
+            dev_next = jnp.where(departs, jnp.inf, dev_next)
+            last_done_t = jnp.where(jnp.any(comp_local), t,
+                                    st["last_done_t"])
 
-        # a departed device's stream counts as exhausted (drained() and
-        # next_event_t both read cursor >= s), so the drain early-exit
-        # fires without its dropped samples ever completing
-        cursor = jnp.where(departs, s, st["cursor"] + done)
-        # next sample starts when the device is free AND it has arrived
-        # (no arrival tensor -> back-to-back, the gather compiles out)
-        if static.has_arrive:
-            arrive_next = arrive_c[jnp.arange(n, dtype=jnp.int32),
-                                   jnp.clip(cursor, 0, s - 1)]
-            start_next = jnp.maximum(st["dev_next"], arrive_next)
-        else:
-            start_next = st["dev_next"]
-        dev_next = jnp.where(done,
-                             defer_offline(start_next + dev_latency, c),
-                             st["dev_next"])
-        dev_next = jnp.where(departs, jnp.inf, dev_next)
-        last_done_t = jnp.where(jnp.any(comp_local), t, st["last_done_t"])
+        with jax.named_scope("jaxsim.queue"):
+            pos = st["tail"] + jnp.cumsum(fwd_mask, dtype=jnp.int32) - 1
+            # non-forwarding rows aim at index cap and are dropped: an
+            # in-ring dummy slot would collide with a REAL append once a
+            # small queue_cap wraps tail past it (duplicate-index
+            # scatter, order-dependent)
+            posm = jnp.where(fwd_mask, pos % cap, cap)
+            q_start = st["q_start"].at[posm].set(
+                st["dev_next"] - dev_latency, mode="drop")
+            q_dev = st["q_dev"].at[posm].set(
+                jnp.arange(n, dtype=jnp.int32), mode="drop")
+            q_samp = st["q_samp"].at[posm].set(cj, mode="drop")
+            tail = st["tail"] + jnp.sum(fwd_mask, dtype=jnp.int32)
 
-        # --- server dynamic batching --------------------------------------
-        qlen = tail - st["head"]
-        can_pop = (t >= st["busy_until"]) & (qlen > 0) & go
-        sidx = st["server_idx"]
-        braw = jnp.minimum(qlen, srv["max_batch"][sidx])
-        b = jnp.max(jnp.where(ladder <= braw, ladder, 1))
-        lanes = jnp.arange(MAX_POP, dtype=jnp.int32)
-        take = (lanes < b) & can_pop
-        qidx = (st["head"] + lanes) % cap
-        starts = q_start[qidx]          # updated arrays: same-event entries
-        devs = jnp.where(take, q_dev[qidx], 0)
-        samps = q_samp[qidx]
-        lat_b = base_lat[sidx] * (1.0 + scaling[sidx]
-                                  * (b - 1).astype(jnp.float32))
-        # exact launch: t is the batch-finish time when the queue was
-        # backed up, or the arrival of the sample that made it non-empty —
-        # by construction never before any popped sample was enqueued
-        finish = t + lat_b
-        latency = finish - starts
-        met_srv = (latency <= slo[devs]) & take
-        win_met = win_met.at[devs].add(met_srv)
-        win_total = win_total.at[devs].add(take)
-        tot_met = tot_met.at[devs].add(met_srv)
-        tot = tot.at[devs].add(take)
-        correct = correct.at[devs].add(
-            take * ch[devs, samps, sidx])
-        head = st["head"] + jnp.where(can_pop, b, 0)
-        busy_until = jnp.where(can_pop, finish, st["busy_until"])
-        last_batch = jnp.where(can_pop, b, st["last_batch"])
-        last_done_t = jnp.where(can_pop, finish, last_done_t)
-        max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
-                             st["max_qlen"])
+            # --- server dynamic batching ----------------------------------
+            qlen = tail - st["head"]
+            can_pop = (t >= st["busy_until"]) & (qlen > 0) & go
+            sidx = st["server_idx"]
+            braw = jnp.minimum(qlen, srv["max_batch"][sidx])
+            b = jnp.max(jnp.where(ladder <= braw, ladder, 1))
+            lanes = jnp.arange(MAX_POP, dtype=jnp.int32)
+            take = (lanes < b) & can_pop
+            qidx = (st["head"] + lanes) % cap
+            starts = q_start[qidx]      # updated arrays: same-event entries
+            devs = jnp.where(take, q_dev[qidx], 0)
+            samps = q_samp[qidx]
+            lat_b = base_lat[sidx] * (1.0 + scaling[sidx]
+                                      * (b - 1).astype(jnp.float32))
+            # exact launch: t is the batch-finish time when the queue was
+            # backed up, or the arrival of the sample that made it
+            # non-empty — by construction never before any popped sample
+            # was enqueued
+            finish = t + lat_b
+            latency = finish - starts
+            met_srv = (latency <= slo[devs]) & take
+            win_met = win_met.at[devs].add(met_srv)
+            win_total = win_total.at[devs].add(take)
+            tot_met = tot_met.at[devs].add(met_srv)
+            tot = tot.at[devs].add(take)
+            correct = correct.at[devs].add(
+                take * ch[devs, samps, sidx])
+            head = st["head"] + jnp.where(can_pop, b, 0)
+            busy_until = jnp.where(can_pop, finish, st["busy_until"])
+            last_batch = jnp.where(can_pop, b, st["last_batch"])
+            last_done_t = jnp.where(can_pop, finish, last_done_t)
+            max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
+                                 st["max_qlen"])
 
-        st2 = dict(
-            st, t=jnp.where(go, t, st["t"]), n_events=st["n_events"] + go,
-            dev_next=dev_next, cursor=cursor, win_met=win_met,
-            win_total=win_total, tot_met=tot_met, tot=tot, correct=correct,
-            fwd=st_fwd, q_start=q_start, q_dev=q_dev, q_samp=q_samp,
-            head=head, tail=tail, busy_until=busy_until,
-            last_batch=last_batch, last_done_t=last_done_t,
-            max_qlen=max_qlen, k=st["k"] + go)
-        # the pre-extracted frontier: the only place it ever moves — a
-        # window boundary touches no queue/cursor/server-timing state
-        st2["frontier"] = jnp.where(go, next_event_t(st2), st["frontier"])
+        with jax.named_scope("jaxsim.frontier"):
+            st2 = dict(
+                st, t=jnp.where(go, t, st["t"]),
+                n_events=st["n_events"] + go,
+                dev_next=dev_next, cursor=cursor, win_met=win_met,
+                win_total=win_total, tot_met=tot_met, tot=tot,
+                correct=correct, fwd=st_fwd, q_start=q_start, q_dev=q_dev,
+                q_samp=q_samp, head=head, tail=tail, busy_until=busy_until,
+                last_batch=last_batch, last_done_t=last_done_t,
+                max_qlen=max_qlen, k=st["k"] + go)
+            # the pre-extracted frontier: the only place it ever moves — a
+            # window boundary touches no queue/cursor/server-timing state
+            st2["frontier"] = jnp.where(go, next_event_t(st2),
+                                        st["frontier"])
         return st2
 
     completion_seg, apply_append_seg, pop_calc_seg = (
@@ -1167,64 +1204,77 @@ def _engine_fns(static: JaxSimStatic):
         flat engine's, though ``n_events`` counts the extra iterations.
         """
         t = st["frontier"]
-        sidx = jnp.argmin(st["seg_min"]).astype(jnp.int32)
-        has_due = go & (st["seg_min"][sidx] <= t)
-        base = sidx * G
-        dev = {
-            "dev_next": st["dev_next"], "cursor": st["cursor"],
-            "thresh": st["thresh"], "win_met": st["win_met"],
-            "win_total": st["win_total"], "tot_met": st["tot_met"],
-            "tot": st["tot"], "correct": st["correct"], "fwd": st["fwd"],
-            "dev_latency": c["dev_latency"], "slo": c["slo"],
-            "leave_t": c["leave_t"], "off_start": c["off_start"],
-            "off_for": c["off_for"],
-            "conf_flat": c["conf"].reshape(-1),
-            "cl_flat": c["cl"].reshape(-1),
-            "arrive_flat": (c["arrive"].reshape(-1) if static.has_arrive
-                            else c["arrive"]),
-        }
-        seg_upd, append, seg_min_new, comp_any = completion_seg(
-            dev, t, base, base, has_due)
-        wb = {key: jax.lax.dynamic_update_slice_in_dim(st[key], upd_k,
-                                                       base, axis=0)
-              for key, upd_k in seg_upd.items()}
-        seg_min = st["seg_min"].at[sidx].set(
-            jnp.where(has_due, seg_min_new, st["seg_min"][sidx]))
-        t_dev = jnp.min(seg_min)
-        q_start, q_dev, q_samp, tail = apply_append_seg(
-            st["q_start"], st["q_dev"], st["q_samp"], st["tail"], append)
-        last_done_t = jnp.where(comp_any, t, st["last_done_t"])
+        with jax.named_scope("jaxsim.frontier"):
+            sidx = jnp.argmin(st["seg_min"]).astype(jnp.int32)
+            has_due = go & (st["seg_min"][sidx] <= t)
+            base = sidx * G
+        with jax.named_scope("jaxsim.devices"):
+            dev = {
+                "dev_next": st["dev_next"], "cursor": st["cursor"],
+                "thresh": st["thresh"], "win_met": st["win_met"],
+                "win_total": st["win_total"], "tot_met": st["tot_met"],
+                "tot": st["tot"], "correct": st["correct"],
+                "fwd": st["fwd"],
+                "dev_latency": c["dev_latency"], "slo": c["slo"],
+                "leave_t": c["leave_t"], "off_start": c["off_start"],
+                "off_for": c["off_for"],
+                "conf_flat": c["conf"].reshape(-1),
+                "cl_flat": c["cl"].reshape(-1),
+                "arrive_flat": (c["arrive"].reshape(-1)
+                                if static.has_arrive else c["arrive"]),
+            }
+            seg_upd, append, seg_min_new, comp_any = completion_seg(
+                dev, t, base, base, has_due)
+            wb = {key: jax.lax.dynamic_update_slice_in_dim(
+                      st[key], upd_k, base, axis=0)
+                  for key, upd_k in seg_upd.items()}
+            last_done_t = jnp.where(comp_any, t, st["last_done_t"])
+        with jax.named_scope("jaxsim.frontier"):
+            seg_min = st["seg_min"].at[sidx].set(
+                jnp.where(has_due, seg_min_new, st["seg_min"][sidx]))
+            t_dev = jnp.min(seg_min)
 
-        # --- server dynamic batching: only once the instant's completions
-        # have all drained (t_dev > t), so ties across segments enqueue in
-        # full device-index order before the ladder sizes the batch ------
-        qlen = tail - st["head"]
-        can_pop = go & (t >= st["busy_until"]) & (qlen > 0) & (t_dev > t)
-        p = pop_calc_seg(t, q_start, q_dev, q_samp, st["head"],
-                         st["server_idx"], srv, qlen, can_pop)
-        met_srv = (p["latency"] <= c["slo"][p["devs"]]) & p["take"]
-        win_met = wb["win_met"].at[p["devs"]].add(met_srv)
-        win_total = wb["win_total"].at[p["devs"]].add(p["take"])
-        tot_met = wb["tot_met"].at[p["devs"]].add(met_srv)
-        tot = wb["tot"].at[p["devs"]].add(p["take"])
-        correct = wb["correct"].at[p["devs"]].add(
-            p["take"] * c["ch"][p["devs"], p["samps"], st["server_idx"]])
-        head = st["head"] + jnp.where(can_pop, p["b"], 0)
-        busy_until = jnp.where(can_pop, p["finish"], st["busy_until"])
-        last_batch = jnp.where(can_pop, p["b"], st["last_batch"])
-        last_done_t = jnp.where(can_pop, p["finish"], last_done_t)
-        max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
-                             st["max_qlen"])
+        with jax.named_scope("jaxsim.queue"):
+            q_start, q_dev, q_samp, tail = apply_append_seg(
+                st["q_start"], st["q_dev"], st["q_samp"], st["tail"],
+                append)
+            # --- server dynamic batching: only once the instant's
+            # completions have all drained (t_dev > t), so ties across
+            # segments enqueue in full device-index order before the
+            # ladder sizes the batch -----------------------------------
+            qlen = tail - st["head"]
+            can_pop = (go & (t >= st["busy_until"]) & (qlen > 0)
+                       & (t_dev > t))
+            p = pop_calc_seg(t, q_start, q_dev, q_samp, st["head"],
+                             st["server_idx"], srv, qlen, can_pop)
+            met_srv = (p["latency"] <= c["slo"][p["devs"]]) & p["take"]
+            win_met = wb["win_met"].at[p["devs"]].add(met_srv)
+            win_total = wb["win_total"].at[p["devs"]].add(p["take"])
+            tot_met = wb["tot_met"].at[p["devs"]].add(met_srv)
+            tot = wb["tot"].at[p["devs"]].add(p["take"])
+            correct = wb["correct"].at[p["devs"]].add(
+                p["take"] * c["ch"][p["devs"], p["samps"],
+                                    st["server_idx"]])
+            head = st["head"] + jnp.where(can_pop, p["b"], 0)
+            busy_until = jnp.where(can_pop, p["finish"], st["busy_until"])
+            last_batch = jnp.where(can_pop, p["b"], st["last_batch"])
+            last_done_t = jnp.where(can_pop, p["finish"], last_done_t)
+            max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
+                                 st["max_qlen"])
 
-        st2 = dict(
-            st, t=jnp.where(go, t, st["t"]), n_events=st["n_events"] + go,
-            dev_next=wb["dev_next"], cursor=wb["cursor"], win_met=win_met,
-            win_total=win_total, tot_met=tot_met, tot=tot, correct=correct,
-            fwd=wb["fwd"], q_start=q_start, q_dev=q_dev, q_samp=q_samp,
-            head=head, tail=tail, busy_until=busy_until,
-            last_batch=last_batch, last_done_t=last_done_t,
-            seg_min=seg_min, max_qlen=max_qlen, k=st["k"] + go)
-        st2["frontier"] = jnp.where(go, next_event_t(st2), st["frontier"])
+        with jax.named_scope("jaxsim.frontier"):
+            st2 = dict(
+                st, t=jnp.where(go, t, st["t"]),
+                n_events=st["n_events"] + go,
+                dev_next=wb["dev_next"], cursor=wb["cursor"],
+                win_met=win_met, win_total=win_total, tot_met=tot_met,
+                tot=tot, correct=correct, fwd=wb["fwd"], q_start=q_start,
+                q_dev=q_dev, q_samp=q_samp, head=head, tail=tail,
+                busy_until=busy_until, last_batch=last_batch,
+                last_done_t=last_done_t, seg_min=seg_min,
+                max_qlen=max_qlen, k=st["k"] + go)
+            st2["frontier"] = jnp.where(go, next_event_t(st2),
+                                        st["frontier"])
         return st2
 
     def lane_boundary(st, c, go):
@@ -1367,6 +1417,7 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
     boundary_v = jax.vmap(lane_boundary, in_axes=(0, 0, 0))
     metrics_v = jax.vmap(lane_metrics)
 
+    @jax.named_scope("jaxsim.frontier")
     def event_flags(st):
         # an event is due iff it lands inside the lane's current window
         # and the per-window safety cap has room; otherwise the lane's
@@ -1376,32 +1427,38 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
                 & (st["k"] < static.max_events_per_window))
 
     def body(st):
-        st = event_v(st, consts, srv, event_flags(st))
+        # one named scope per phase of a trip names its ops in a profiler
+        # trace (docs/ARCHITECTURE.md, "Tracing"); no op changes
+        with jax.named_scope("jaxsim.event"):
+            st = event_v(st, consts, srv, event_flags(st))
         # boundary after the event of the same iteration: a lane whose
         # frontier just left the window takes its boundary immediately
         # (same per-lane op sequence as event-then-boundary, fewer trips)
-        go_b = st["active"] & ~event_flags(st)
+        with jax.named_scope("jaxsim.boundary"):
+            go_b = st["active"] & ~event_flags(st)
 
-        def do_boundary(op):
-            st_, go_ = op
-            return boundary_v(st_, consts, go_)
+            def do_boundary(op):
+                st_, go_ = op
+                return boundary_v(st_, consts, go_)
 
-        def skip_boundary(op):
-            st_, _ = op
-            return ({k: st_[k] for k in BOUNDARY_FIELDS},
-                    {k: jnp.zeros((bsz,), jnp.float32) for k in TRACE_KEYS})
+            def skip_boundary(op):
+                st_, _ = op
+                return ({k: st_[k] for k in BOUNDARY_FIELDS},
+                        {k: jnp.zeros((bsz,), jnp.float32)
+                         for k in TRACE_KEYS})
 
-        upd, row = jax.lax.cond(jnp.any(go_b), do_boundary, skip_boundary,
-                                (st, go_b))
-        # lanes not at a boundary write their row out of bounds and are
-        # dropped: one gather-free scatter per key, no per-lane select
-        # over the trace buffers (an active lane's w is < n_windows, so
-        # in-bounds exactly for the lanes that really close a window)
-        bidx = jnp.arange(bsz, dtype=jnp.int32)
-        wj = jnp.where(go_b, st["w"], static.n_windows)
-        traces = {key: st["traces"][key].at[bidx, wj].set(row[key],
-                                                          mode="drop")
-                  for key in TRACE_KEYS}
+            upd, row = jax.lax.cond(jnp.any(go_b), do_boundary,
+                                    skip_boundary, (st, go_b))
+            # lanes not at a boundary write their row out of bounds and
+            # are dropped: one gather-free scatter per key, no per-lane
+            # select over the trace buffers (an active lane's w is <
+            # n_windows, so in-bounds exactly for the lanes that really
+            # close a window)
+            bidx = jnp.arange(bsz, dtype=jnp.int32)
+            wj = jnp.where(go_b, st["w"], static.n_windows)
+            traces = {key: st["traces"][key].at[bidx, wj].set(
+                          row[key], mode="drop")
+                      for key in TRACE_KEYS}
         return dict(st, traces=traces, **upd)
 
     def finalize(st):
@@ -1513,95 +1570,108 @@ def _device_engine(static: JaxSimStatic, k: int, axis: str):
     def event(st, c, srv, go):
         t = st["frontier"]
         off = shard_off()
-        loc_best = jnp.min(st["seg_min"])
-        lidx = jnp.argmin(st["seg_min"]).astype(jnp.int32)
-        t_dev0 = pmin(loc_best)
-        # owner = globally lowest-index segment attaining the frontier
-        # min (ties across shards resolve to the lowest shard, matching
-        # the local engine's argmin over the concatenated seg_min)
-        cand = jnp.where(
-            loc_best == t_dev0,
-            jax.lax.axis_index(axis).astype(jnp.int32) * n_segs_loc + lidx,
-            jnp.int32(2 ** 30))
-        owner = pmin(cand)
-        mine = cand == owner
-        has_due = go & (t_dev0 <= t) & mine
-        base = jnp.where(mine, lidx, 0) * G
-        dev = {
-            "dev_next": st["dev_next"], "cursor": st["cursor"],
-            "thresh": st["thresh"], "win_met": st["win_met"],
-            "win_total": st["win_total"], "tot_met": st["tot_met"],
-            "tot": st["tot"], "correct": st["correct"], "fwd": st["fwd"],
-            "dev_latency": c["dev_latency"], "slo": c["slo"],
-            "leave_t": c["leave_t"], "off_start": c["off_start"],
-            "off_for": c["off_for"],
-            "conf_flat": c["conf"].reshape(-1),
-            "cl_flat": c["cl"].reshape(-1),
-            "arrive_flat": (c["arrive"].reshape(-1) if static.has_arrive
-                            else c["arrive"]),
-        }
-        seg_upd, append, seg_min_new, comp_any_loc = completion(
-            dev, t, base, off + base, has_due)
-        wb = {key: jax.lax.dynamic_update_slice_in_dim(st[key], upd_k,
-                                                       base, axis=0)
-              for key, upd_k in seg_upd.items()}
-        widx = jnp.where(mine, lidx, 0)
-        seg_min = st["seg_min"].at[widx].set(
-            jnp.where(has_due, seg_min_new, st["seg_min"][widx]))
-        t_dev = pmin(jnp.min(seg_min))
-        # replicate the owner's append buffer (all-zero off-owner)
-        ex = psum(dict(append, comp_any=comp_any_loc.astype(jnp.int32)))
-        comp_any = ex.pop("comp_any") > 0
-        q_start, q_dev, q_samp, tail = apply_append(
-            st["q_start"], st["q_dev"], st["q_samp"], st["tail"], ex)
-        last_done_t = jnp.where(comp_any, t, st["last_done_t"])
+        with jax.named_scope("jaxsim.frontier"):
+            loc_best = jnp.min(st["seg_min"])
+            lidx = jnp.argmin(st["seg_min"]).astype(jnp.int32)
+            t_dev0 = pmin(loc_best)
+            # owner = globally lowest-index segment attaining the frontier
+            # min (ties across shards resolve to the lowest shard,
+            # matching the local engine's argmin over the concatenated
+            # seg_min)
+            cand = jnp.where(
+                loc_best == t_dev0,
+                jax.lax.axis_index(axis).astype(jnp.int32) * n_segs_loc
+                + lidx,
+                jnp.int32(2 ** 30))
+            owner = pmin(cand)
+            mine = cand == owner
+            has_due = go & (t_dev0 <= t) & mine
+            base = jnp.where(mine, lidx, 0) * G
+        with jax.named_scope("jaxsim.devices"):
+            dev = {
+                "dev_next": st["dev_next"], "cursor": st["cursor"],
+                "thresh": st["thresh"], "win_met": st["win_met"],
+                "win_total": st["win_total"], "tot_met": st["tot_met"],
+                "tot": st["tot"], "correct": st["correct"],
+                "fwd": st["fwd"],
+                "dev_latency": c["dev_latency"], "slo": c["slo"],
+                "leave_t": c["leave_t"], "off_start": c["off_start"],
+                "off_for": c["off_for"],
+                "conf_flat": c["conf"].reshape(-1),
+                "cl_flat": c["cl"].reshape(-1),
+                "arrive_flat": (c["arrive"].reshape(-1)
+                                if static.has_arrive else c["arrive"]),
+            }
+            seg_upd, append, seg_min_new, comp_any_loc = completion(
+                dev, t, base, off + base, has_due)
+            wb = {key: jax.lax.dynamic_update_slice_in_dim(
+                      st[key], upd_k, base, axis=0)
+                  for key, upd_k in seg_upd.items()}
+        with jax.named_scope("jaxsim.frontier"):
+            widx = jnp.where(mine, lidx, 0)
+            seg_min = st["seg_min"].at[widx].set(
+                jnp.where(has_due, seg_min_new, st["seg_min"][widx]))
+            t_dev = pmin(jnp.min(seg_min))
+        with jax.named_scope("jaxsim.queue"):
+            # replicate the owner's append buffer (all-zero off-owner)
+            ex = psum(dict(append,
+                           comp_any=comp_any_loc.astype(jnp.int32)))
+            comp_any = ex.pop("comp_any") > 0
+            q_start, q_dev, q_samp, tail = apply_append(
+                st["q_start"], st["q_dev"], st["q_samp"], st["tail"], ex)
+            last_done_t = jnp.where(comp_any, t, st["last_done_t"])
 
-        qlen = tail - st["head"]
-        can_pop = go & (t >= st["busy_until"]) & (qlen > 0) & (t_dev > t)
-        p = pop_calc(t, q_start, q_dev, q_samp, st["head"],
-                     st["server_idx"], srv, qlen, can_pop)
-        # popped entries' slo / heavy-correctness live on the owning
-        # shards: masked local gathers, one psum to replicate
-        ldev = p["devs"] - off
-        inr = (ldev >= 0) & (ldev < n_loc) & p["take"]
-        lclip = jnp.clip(ldev, 0, n_loc - 1)
-        g = psum({
-            "slo": jnp.where(inr, c["slo"][lclip], 0.0),
-            "ch": jnp.where(inr,
-                            c["ch"][lclip, p["samps"], st["server_idx"]],
-                            0),
-        })
-        met_srv = (p["latency"] <= g["slo"]) & p["take"]
-        win_met = wb["win_met"].at[lclip].add(jnp.where(inr, met_srv,
-                                                        False))
-        win_total = wb["win_total"].at[lclip].add(jnp.where(inr, p["take"],
-                                                            False))
-        tot_met = wb["tot_met"].at[lclip].add(jnp.where(inr, met_srv,
-                                                        False))
-        tot = wb["tot"].at[lclip].add(jnp.where(inr, p["take"], False))
-        correct = wb["correct"].at[lclip].add(
-            jnp.where(inr, p["take"] * g["ch"], 0))
-        head = st["head"] + jnp.where(can_pop, p["b"], 0)
-        busy_until = jnp.where(can_pop, p["finish"], st["busy_until"])
-        last_batch = jnp.where(can_pop, p["b"], st["last_batch"])
-        last_done_t = jnp.where(can_pop, p["finish"], last_done_t)
-        max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
-                             st["max_qlen"])
+            qlen = tail - st["head"]
+            can_pop = (go & (t >= st["busy_until"]) & (qlen > 0)
+                       & (t_dev > t))
+            p = pop_calc(t, q_start, q_dev, q_samp, st["head"],
+                         st["server_idx"], srv, qlen, can_pop)
+            # popped entries' slo / heavy-correctness live on the owning
+            # shards: masked local gathers, one psum to replicate
+            ldev = p["devs"] - off
+            inr = (ldev >= 0) & (ldev < n_loc) & p["take"]
+            lclip = jnp.clip(ldev, 0, n_loc - 1)
+            g = psum({
+                "slo": jnp.where(inr, c["slo"][lclip], 0.0),
+                "ch": jnp.where(inr,
+                                c["ch"][lclip, p["samps"],
+                                        st["server_idx"]],
+                                0),
+            })
+            met_srv = (p["latency"] <= g["slo"]) & p["take"]
+            win_met = wb["win_met"].at[lclip].add(
+                jnp.where(inr, met_srv, False))
+            win_total = wb["win_total"].at[lclip].add(
+                jnp.where(inr, p["take"], False))
+            tot_met = wb["tot_met"].at[lclip].add(
+                jnp.where(inr, met_srv, False))
+            tot = wb["tot"].at[lclip].add(jnp.where(inr, p["take"], False))
+            correct = wb["correct"].at[lclip].add(
+                jnp.where(inr, p["take"] * g["ch"], 0))
+            head = st["head"] + jnp.where(can_pop, p["b"], 0)
+            busy_until = jnp.where(can_pop, p["finish"], st["busy_until"])
+            last_batch = jnp.where(can_pop, p["b"], st["last_batch"])
+            last_done_t = jnp.where(can_pop, p["finish"], last_done_t)
+            max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
+                                 st["max_qlen"])
 
-        st2 = dict(
-            st, t=jnp.where(go, t, st["t"]), n_events=st["n_events"] + go,
-            dev_next=wb["dev_next"], cursor=wb["cursor"], win_met=win_met,
-            win_total=win_total, tot_met=tot_met, tot=tot, correct=correct,
-            fwd=wb["fwd"], q_start=q_start, q_dev=q_dev, q_samp=q_samp,
-            head=head, tail=tail, busy_until=busy_until,
-            last_batch=last_batch, last_done_t=last_done_t,
-            seg_min=seg_min, max_qlen=max_qlen, k=st["k"] + go)
-        qlen2 = tail - head
-        t_srv = jnp.where(qlen2 > 0,
-                          jnp.where(busy_until > t, busy_until, t),
-                          jnp.inf)
-        st2["frontier"] = jnp.where(go, jnp.minimum(t_dev, t_srv),
-                                    st["frontier"])
+        with jax.named_scope("jaxsim.frontier"):
+            st2 = dict(
+                st, t=jnp.where(go, t, st["t"]),
+                n_events=st["n_events"] + go,
+                dev_next=wb["dev_next"], cursor=wb["cursor"],
+                win_met=win_met, win_total=win_total, tot_met=tot_met,
+                tot=tot, correct=correct, fwd=wb["fwd"], q_start=q_start,
+                q_dev=q_dev, q_samp=q_samp, head=head, tail=tail,
+                busy_until=busy_until, last_batch=last_batch,
+                last_done_t=last_done_t, seg_min=seg_min,
+                max_qlen=max_qlen, k=st["k"] + go)
+            qlen2 = tail - head
+            t_srv = jnp.where(qlen2 > 0,
+                              jnp.where(busy_until > t, busy_until, t),
+                              jnp.inf)
+            st2["frontier"] = jnp.where(go, jnp.minimum(t_dev, t_srv),
+                                        st["frontier"])
         return st2
 
     # --- window boundary, split into collective-free cond bodies with
@@ -1777,31 +1847,36 @@ def _run_core_device(static, k, axis, params, srv, conf, cl, ch, arrive,
                   c_upper=c_upper, off_start=off_start, off_for=off_for,
                   join_t=join_t, leave_t=leave_t)
 
+    @jax.named_scope("jaxsim.frontier")
     def event_go(st):
         t_end = (st["w"] + 1).astype(jnp.float32) * static.window
         return (st["active"] & (st["frontier"] <= t_end)
                 & (st["k"] < static.max_events_per_window))
 
     def body(st):
-        st = e["event"](st, consts, srv, event_go(st))
-        go_b = st["active"] & ~event_go(st)
-        pre = jax.lax.cond(go_b,
-                           lambda s_: e["boundary_pre"](s_, consts),
-                           e["zeros_pre"], st)
-        pre_g = e["psum"](pre)
-        mid = jax.lax.cond(
-            go_b,
-            lambda op: e["boundary_mid"](op[0], consts, op[1]),
-            lambda op: e["zeros_mid"](op[0]), (st, pre_g))
-        sums_g = e["psum"](mid["sums"])
-        upd, row = jax.lax.cond(
-            go_b,
-            lambda op: e["boundary_fin"](op[0], consts, op[1], op[2],
-                                         op[3]),
-            lambda op: e["skip_fin"](op[0]), (st, mid, sums_g, pre_g))
-        wj = jnp.where(go_b, st["w"], static.n_windows)
-        traces = {key: st["traces"][key].at[wj].set(row[key], mode="drop")
-                  for key in TRACE_KEYS}
+        # the local engines' phase scopes (``_batched_engine``)
+        with jax.named_scope("jaxsim.event"):
+            st = e["event"](st, consts, srv, event_go(st))
+        with jax.named_scope("jaxsim.boundary"):
+            go_b = st["active"] & ~event_go(st)
+            pre = jax.lax.cond(go_b,
+                               lambda s_: e["boundary_pre"](s_, consts),
+                               e["zeros_pre"], st)
+            pre_g = e["psum"](pre)
+            mid = jax.lax.cond(
+                go_b,
+                lambda op: e["boundary_mid"](op[0], consts, op[1]),
+                lambda op: e["zeros_mid"](op[0]), (st, pre_g))
+            sums_g = e["psum"](mid["sums"])
+            upd, row = jax.lax.cond(
+                go_b,
+                lambda op: e["boundary_fin"](op[0], consts, op[1], op[2],
+                                             op[3]),
+                lambda op: e["skip_fin"](op[0]), (st, mid, sums_g, pre_g))
+            wj = jnp.where(go_b, st["w"], static.n_windows)
+            traces = {key: st["traces"][key].at[wj].set(row[key],
+                                                        mode="drop")
+                      for key in TRACE_KEYS}
         return dict(st, traces=traces, **upd)
 
     st0 = e["init"](consts)
@@ -1886,28 +1961,30 @@ def run_device_sharded(spec: JaxSimSpec, streams, dev_latency, slo,
                    join_t=join_t, leave_t=leave_t,
                    frontier_seg=True if frontier_seg is None
                    else frontier_seg)
-    static, params, srv, arrays, b, n = _prepare(
-        [spec], streams, dev_latency, slo, servers, tier_ids, c_upper,
-        offline_start, offline_for, join_t, leave_t,
-        frontier_seg=frontier_seg, device_shards=k)
-    if b != 1:
-        raise ValueError("run_device_sharded runs one sweep point (B=1); "
-                         f"got a stream batch of {b}")
-    params1 = {key: v[0] for key, v in params.items()}
-    arrays1 = tuple(a[0] for a in arrays)
+    with _host_span("jaxsim.prepare"):
+        static, params, srv, arrays, b, n = _prepare(
+            [spec], streams, dev_latency, slo, servers, tier_ids, c_upper,
+            offline_start, offline_for, join_t, leave_t,
+            frontier_seg=frontier_seg, device_shards=k)
+        if b != 1:
+            raise ValueError("run_device_sharded runs one sweep point "
+                             f"(B=1); got a stream batch of {b}")
+        params1 = {key: v[0] for key, v in params.items()}
+        arrays1 = tuple(a[0] for a in arrays)
     dev_sh = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec(device_axis_of(mesh)))
     rep_sh = jax.sharding.NamedSharding(mesh,
                                         jax.sharding.PartitionSpec())
-    core = _make_core_device(static, mesh)
-    out = core(jax.device_put(params1, rep_sh),
-               jax.device_put(srv, rep_sh),
-               *(jax.device_put(a, rep_sh if i == 7 else dev_sh)
-                 for i, a in enumerate(arrays1)))
-    out = dict(out)
-    for key in _DEVICE_OUT_SHARDED:
-        out[key] = np.asarray(out[key])[:n]
-    out["n_events"] = np.asarray(out["n_events"])
+    with _host_span("jaxsim.transfer"):
+        args = jax.block_until_ready(
+            (jax.device_put(params1, rep_sh), jax.device_put(srv, rep_sh),
+             *(jax.device_put(a, rep_sh if i == 7 else dev_sh)
+               for i, a in enumerate(arrays1))))
+    with _host_span("jaxsim.execute"):
+        out = dict(_make_core_device(static, mesh)(*args))
+        for key in _DEVICE_OUT_SHARDED:
+            out[key] = np.asarray(out[key])[:n]
+        out["n_events"] = np.asarray(out["n_events"])
     stats.points += 1
     stats.events += int(out["n_events"])
     stats.device_sharded_points += 1

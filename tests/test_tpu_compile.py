@@ -15,7 +15,9 @@ Covered:
   under ``pallas`` dispatch;
 * the lane-aligned sim core at fig11_lanes' B=64, and at N=4096, where
   the segmented frontier is on;
-* the device-axis-sharded sim core on the four-chip mesh.
+* the device-axis-sharded sim core on the four-chip mesh;
+* the sim cores' phase scopes (``jaxsim.*``), which must reach the ops'
+  metadata the TPU profiler records.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and several test workers
@@ -79,6 +81,16 @@ def _shapes(tree, sharding):
         tree)
 
 
+SIM_SCOPES = ("jaxsim.event", "jaxsim.devices", "jaxsim.queue",
+              "jaxsim.frontier", "jaxsim.boundary")
+
+
+def _assert_scoped(compiled):
+    text = compiled.as_text()
+    for scope in SIM_SCOPES:
+        assert scope in text, scope
+
+
 def _assert_fits(compiled):
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -136,6 +148,7 @@ def test_lane_core_compiles(one_chip, seeds, n, samples, segmented):
     compiled = core.lower(_shapes(params, one_chip), _shapes(srv, one_chip),
                           *_shapes(arrays, one_chip)).compile()
     _assert_fits(compiled)
+    _assert_scoped(compiled)
 
 
 def test_device_sharded_core_compiles(topo):
@@ -151,3 +164,4 @@ def test_device_sharded_core_compiles(topo):
     compiled = jaxsim._make_core_device(static, mesh).lower(*args).compile()
     assert "all-reduce" in compiled.as_text()
     _assert_fits(compiled)
+    _assert_scoped(compiled)

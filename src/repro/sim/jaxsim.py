@@ -263,9 +263,10 @@ benchmarks/fig_scale.py, pinned by tests/test_scale.py):
   partial sums (``accuracy``, trace thresh/sr/acc) may differ in the
   last ulp (psum reduction order).
 
-``JaxSimSpec.queue_cap`` bounds the replicated server ring (must
-exceed ``MAX_POP``); the realized high-water mark is reported as
-``queue_peak``.
+``JaxSimSpec.queue_cap`` bounds the server ring (must exceed
+``MAX_POP``): each lane's ``cap`` slots of the lane core's flat ring
+(see ``RING_FIELDS``), and the device-sharded core's one replicated
+ring; the realized high-water mark is reported as ``queue_peak``.
 """
 from __future__ import annotations
 
@@ -857,35 +858,92 @@ def _ratio32(num, den):
     return num.astype(jnp.float32) / den.astype(jnp.float32)
 
 
+# The server queue ring: an entry per forwarded sample (its start time,
+# device id and sample index), ``static.cap`` slots per lane. The lane
+# core carries each buffer flat over its lanes, lane i owning slots
+# [i * cap, (i + 1) * cap), so the ring is written and read outside the
+# per-lane vmap and the TPU compiler updates the carried buffer in place
+# (a (B, cap) carry made it copy all three buffers in and out of a flat
+# layout on every trip). The device-sharded core holds one replicated
+# ring, at base 0. All three engines share the helpers below, which is
+# what keeps their rings bitwise alike.
+RING_FIELDS = ("q_start", "q_dev", "q_samp")
+
+
+def _ring_slots(tail, fwd, cap):
+    """Ring slots of one event's appends, and the tail after them.
+
+    Forwarding rows (``fwd`` > 0) take the slots after ``tail`` in row
+    order, mod ``cap``; every other row gets ``cap``, the mark that
+    ``_ring_write`` drops."""
+    pos = tail + jnp.cumsum(fwd, dtype=jnp.int32) - 1
+    return (jnp.where(fwd > 0, pos % cap, cap),
+            tail + jnp.sum(fwd, dtype=jnp.int32))
+
+
+def _ring_write(ring, base, slots, rows, cap):
+    """Write ``rows`` into the flat ring buffers at ``base + slots``.
+
+    A row marked ``cap`` aims at the buffer's own size, past every
+    lane, and is dropped: ``base + cap`` is the next lane's first slot,
+    and an in-ring dummy slot would collide with a REAL append once a
+    small queue_cap wraps tail past it (duplicate-index scatter,
+    order-dependent). A held lane's rows are all marked, so it writes
+    nothing."""
+    size = ring["q_start"].shape[0]
+    idx = jnp.where(slots < cap, base + slots, size).reshape(-1)
+    return {key: ring[key].at[idx].set(rows[key].reshape(-1), mode="drop")
+            for key in RING_FIELDS}
+
+
+def _ring_read(ring, base, head, cap):
+    """The ``MAX_POP`` entries from the ring head on, after the event's
+    appends (a batch may take same-event entries)."""
+    idx = base + (head + jnp.arange(MAX_POP, dtype=jnp.int32)) % cap
+    return {key: ring[key][idx] for key in RING_FIELDS}
+
+
+def _pop_calc(t, popped, server_idx, srv, qlen, can_pop):
+    """The ladder batch launched at ``t`` from the ring head's entries
+    ``popped``: the taken entries' device ids (0 where not taken),
+    samples and latencies. The caller scatters the counters: it owns
+    the (local or full) per-device arrays."""
+    ladder = jnp.asarray(BATCH_LADDER, jnp.int32)
+    braw = jnp.minimum(qlen, srv["max_batch"][server_idx])
+    b = jnp.max(jnp.where(ladder <= braw, ladder, 1))
+    take = (jnp.arange(MAX_POP, dtype=jnp.int32) < b) & can_pop
+    lat_b = srv["base_lat"][server_idx] * (
+        1.0 + srv["scaling"][server_idx] * (b - 1).astype(jnp.float32))
+    # exact launch: t is the batch-finish time when the queue was backed
+    # up, or the arrival of the sample that made it non-empty — by
+    # construction never before any popped sample was enqueued
+    finish = t + lat_b
+    return {"take": take, "devs": jnp.where(take, popped["q_dev"], 0),
+            "samps": popped["q_samp"], "b": b, "finish": finish,
+            "latency": finish - popped["q_start"]}
+
+
 def _seg_phases(static: JaxSimStatic):
     """Shared segment-event arithmetic for the segmented engines.
 
     The local segmented lane (``_engine_fns`` with ``static.seg > 0``)
     and the device-sharded core (``_device_engine``) are the SAME math
-    with a psum exchange spliced between these phases — factoring the
-    phases here makes their bitwise parity hold by construction:
-
-    * ``completion(dev, t, base, gbase, has_due)`` — all device
-      completions of one G-wide segment at instant ``t``. ``dev`` holds
-      the owning array set (full arrays locally, the shard's local slice
-      sharded) with flattened stream views; ``base`` is the segment
-      start within those arrays and ``gbase`` its global device-id base.
-      Returns ``(seg_upd, append, seg_min_new, comp_any)`` — per-segment
-      state slices to write back at ``base``, a G-wide append buffer
-      with GLOBAL device ids (all-zero when ``has_due`` is false, so a
-      psum over shards reproduces the owner's buffer), the segment's new
-      partial min, and whether any local completion happened.
-    * ``apply_append(q_start, q_dev, q_samp, tail, append)`` — ring
-      writes for the buffer; pure in replicated state, so every shard
-      applies the identical update.
-    * ``pop_calc(t, q_start, q_dev, q_samp, head, server_idx, srv,
-      qlen, can_pop)`` — the ladder batch assembled from the ring head;
-      returns the popped lanes' global device ids / samples / latencies.
-      Counter scatters happen at the caller, which owns the (local or
-      full) per-device arrays.
+    with a psum exchange spliced between the completion and the queue
+    (``_ring_*``, ``_pop_calc``) — factoring it here makes their bitwise
+    parity hold by construction. Returns
+    ``completion(dev, t, base, gbase, has_due)``: all device completions
+    of one G-wide segment at instant ``t``. ``dev`` holds the owning
+    array set (full arrays locally, the shard's local slice sharded)
+    with flattened stream views; ``base`` is the segment start within
+    those arrays and ``gbase`` its global device-id base. It returns
+    ``(seg_upd, append, seg_min_new, comp_any)`` — per-segment state
+    slices to write back at ``base``, a G-wide append buffer (the
+    ``RING_FIELDS`` rows with GLOBAL device ids, and ``fwd``; all-zero
+    when ``has_due`` is false, so a psum over shards reproduces the
+    owner's buffer), the segment's new partial min, and whether any
+    local completion happened.
     """
-    G, s, cap = static.seg, static.samples_per_device, static.cap
-    ladder = jnp.asarray(BATCH_LADDER, jnp.int32)
+    G, s = static.seg, static.samples_per_device
 
     def completion(dev, t, base, gbase, has_due):
         def dsl(a):
@@ -930,45 +988,17 @@ def _seg_phases(static: JaxSimStatic):
             "fwd": dsl(dev["fwd"]) + fwd_mask,
         }
         append = {
-            "start": jnp.where(fwd_mask, dn - lat, 0.0).astype(jnp.float32),
-            "dev": jnp.where(fwd_mask, gbase + ar, 0).astype(jnp.int32),
-            "samp": jnp.where(fwd_mask, cj, 0).astype(jnp.int32),
+            "q_start": jnp.where(fwd_mask, dn - lat,
+                                 0.0).astype(jnp.float32),
+            "q_dev": jnp.where(fwd_mask, gbase + ar, 0).astype(jnp.int32),
+            "q_samp": jnp.where(fwd_mask, cj, 0).astype(jnp.int32),
             "fwd": fwd_mask.astype(jnp.int32),
         }
         with jax.named_scope("jaxsim.frontier"):
             seg_min_new = jnp.min(jnp.where(cursor2 < s, dn2, jnp.inf))
         return seg_upd, append, seg_min_new, jnp.any(comp_local)
 
-    def apply_append(q_start, q_dev, q_samp, tail, append):
-        fwd = append["fwd"] > 0
-        pos = tail + jnp.cumsum(append["fwd"]) - 1
-        # non-forwarding rows aim at index cap and are dropped: an
-        # in-ring dummy slot would collide with a REAL append once a
-        # small queue_cap wraps tail past it (duplicate-index scatter,
-        # order-dependent)
-        posm = jnp.where(fwd, pos % cap, cap)
-        q_start = q_start.at[posm].set(append["start"], mode="drop")
-        q_dev = q_dev.at[posm].set(append["dev"], mode="drop")
-        q_samp = q_samp.at[posm].set(append["samp"], mode="drop")
-        return q_start, q_dev, q_samp, tail + jnp.sum(append["fwd"])
-
-    def pop_calc(t, q_start, q_dev, q_samp, head, server_idx, srv, qlen,
-                 can_pop):
-        braw = jnp.minimum(qlen, srv["max_batch"][server_idx])
-        b = jnp.max(jnp.where(ladder <= braw, ladder, 1))
-        lanes = jnp.arange(MAX_POP, dtype=jnp.int32)
-        take = (lanes < b) & can_pop
-        qidx = (head + lanes) % cap
-        starts = q_start[qidx]
-        devs = jnp.where(take, q_dev[qidx], 0)
-        samps = q_samp[qidx]
-        lat_b = srv["base_lat"][server_idx] * (
-            1.0 + srv["scaling"][server_idx] * (b - 1).astype(jnp.float32))
-        finish = t + lat_b
-        return {"take": take, "devs": devs, "samps": samps, "b": b,
-                "finish": finish, "latency": finish - starts}
-
-    return completion, apply_append, pop_calc
+    return completion
 
 
 def _engine_fns(static: JaxSimStatic):
@@ -980,12 +1010,15 @@ def _engine_fns(static: JaxSimStatic):
     masked by ``go`` so a held lane is bitwise frozen. ``_run_core_lanes``
     vmaps these over the flat (B, ...) carry — the ``lax.while_loop``
     itself is never vmapped, so there is no whole-carry select and no
-    cross-lane window synchronization.
+    cross-lane window synchronization. The queue ring is not in the
+    state they see: an event step is ``lane_event`` (completions and the
+    ring slots of their appends), the batched ring write and read
+    (``_batched_engine``), then ``lane_launch`` (the batch launch and
+    the next frontier).
     """
     n, s = static.n_pad, static.samples_per_device
     window, cap = static.window, static.cap
     G = static.seg
-    ladder = jnp.asarray(BATCH_LADDER, jnp.int32)
 
     def defer_offline(t_complete, c):
         # a completion falling inside the device's offline window fires
@@ -1046,9 +1079,6 @@ def _engine_fns(static: JaxSimStatic):
             "tot": jnp.zeros((n,), jnp.int32),
             "correct": jnp.zeros((n,), jnp.int32),
             "fwd": jnp.zeros((n,), jnp.int32),
-            "q_start": jnp.zeros((cap,), jnp.float32),
-            "q_dev": jnp.zeros((cap,), jnp.int32),
-            "q_samp": jnp.zeros((cap,), jnp.int32),
             "head": jnp.zeros((), jnp.int32),
             "tail": jnp.zeros((), jnp.int32),
             "busy_until": jnp.zeros((), jnp.float32),
@@ -1071,12 +1101,14 @@ def _engine_fns(static: JaxSimStatic):
                                       jnp.float32) for key in TRACE_KEYS}
         return st
 
-    def lane_event(st, c, srv, go):
-        """Advance one lane to its frontier event; no-op bitwise if ~go."""
-        conf, cl, ch = c["conf"], c["cl"], c["ch"]
+    def lane_event(st, c, go):
+        """The device completions of one lane's frontier event; no-op
+        bitwise if ~go. Returns the updated state (the tail past the
+        appends; head, clock and frontier not yet moved) and the ring
+        append: ``RING_FIELDS`` rows plus their ``slots``."""
+        conf, cl = c["conf"], c["cl"]
         arrive_c = c["arrive"]
         dev_latency, slo = c["dev_latency"], c["slo"]
-        base_lat, scaling = srv["base_lat"], srv["scaling"]
         t = st["frontier"]
 
         # --- device completions at exactly this instant -------------------
@@ -1123,73 +1155,17 @@ def _engine_fns(static: JaxSimStatic):
                                     st["last_done_t"])
 
         with jax.named_scope("jaxsim.queue"):
-            pos = st["tail"] + jnp.cumsum(fwd_mask, dtype=jnp.int32) - 1
-            # non-forwarding rows aim at index cap and are dropped: an
-            # in-ring dummy slot would collide with a REAL append once a
-            # small queue_cap wraps tail past it (duplicate-index
-            # scatter, order-dependent)
-            posm = jnp.where(fwd_mask, pos % cap, cap)
-            q_start = st["q_start"].at[posm].set(
-                st["dev_next"] - dev_latency, mode="drop")
-            q_dev = st["q_dev"].at[posm].set(
-                jnp.arange(n, dtype=jnp.int32), mode="drop")
-            q_samp = st["q_samp"].at[posm].set(cj, mode="drop")
-            tail = st["tail"] + jnp.sum(fwd_mask, dtype=jnp.int32)
+            slots, tail = _ring_slots(st["tail"], fwd_mask, cap)
+            push = {"slots": slots, "q_start": st["dev_next"] - dev_latency,
+                    "q_dev": jnp.arange(n, dtype=jnp.int32), "q_samp": cj}
+        return dict(st, dev_next=dev_next, cursor=cursor, win_met=win_met,
+                    win_total=win_total, tot_met=tot_met, tot=tot,
+                    correct=correct, fwd=st_fwd, tail=tail,
+                    last_done_t=last_done_t), push
 
-            # --- server dynamic batching ----------------------------------
-            qlen = tail - st["head"]
-            can_pop = (t >= st["busy_until"]) & (qlen > 0) & go
-            sidx = st["server_idx"]
-            braw = jnp.minimum(qlen, srv["max_batch"][sidx])
-            b = jnp.max(jnp.where(ladder <= braw, ladder, 1))
-            lanes = jnp.arange(MAX_POP, dtype=jnp.int32)
-            take = (lanes < b) & can_pop
-            qidx = (st["head"] + lanes) % cap
-            starts = q_start[qidx]      # updated arrays: same-event entries
-            devs = jnp.where(take, q_dev[qidx], 0)
-            samps = q_samp[qidx]
-            lat_b = base_lat[sidx] * (1.0 + scaling[sidx]
-                                      * (b - 1).astype(jnp.float32))
-            # exact launch: t is the batch-finish time when the queue was
-            # backed up, or the arrival of the sample that made it
-            # non-empty — by construction never before any popped sample
-            # was enqueued
-            finish = t + lat_b
-            latency = finish - starts
-            met_srv = (latency <= slo[devs]) & take
-            win_met = win_met.at[devs].add(met_srv)
-            win_total = win_total.at[devs].add(take)
-            tot_met = tot_met.at[devs].add(met_srv)
-            tot = tot.at[devs].add(take)
-            correct = correct.at[devs].add(
-                take * ch[devs, samps, sidx])
-            head = st["head"] + jnp.where(can_pop, b, 0)
-            busy_until = jnp.where(can_pop, finish, st["busy_until"])
-            last_batch = jnp.where(can_pop, b, st["last_batch"])
-            last_done_t = jnp.where(can_pop, finish, last_done_t)
-            max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
-                                 st["max_qlen"])
+    completion_seg = _seg_phases(static) if G else None
 
-        with jax.named_scope("jaxsim.frontier"):
-            st2 = dict(
-                st, t=jnp.where(go, t, st["t"]),
-                n_events=st["n_events"] + go,
-                dev_next=dev_next, cursor=cursor, win_met=win_met,
-                win_total=win_total, tot_met=tot_met, tot=tot,
-                correct=correct, fwd=st_fwd, q_start=q_start, q_dev=q_dev,
-                q_samp=q_samp, head=head, tail=tail, busy_until=busy_until,
-                last_batch=last_batch, last_done_t=last_done_t,
-                max_qlen=max_qlen, k=st["k"] + go)
-            # the pre-extracted frontier: the only place it ever moves — a
-            # window boundary touches no queue/cursor/server-timing state
-            st2["frontier"] = jnp.where(go, next_event_t(st2),
-                                        st["frontier"])
-        return st2
-
-    completion_seg, apply_append_seg, pop_calc_seg = (
-        _seg_phases(static) if G else (None, None, None))
-
-    def lane_event_seg(st, c, srv, go):
+    def lane_event_seg(st, c, go):
         """Segmented-frontier event step: one segment per instant.
 
         The argmin picks the LOWEST-INDEX segment whose partial min
@@ -1202,6 +1178,7 @@ def _engine_fns(static: JaxSimStatic):
         ``t_dev > t`` so it fires only after the last same-instant
         segment — the resulting trajectory is bitwise identical to the
         flat engine's, though ``n_events`` counts the extra iterations.
+        Returns what ``lane_event`` returns.
         """
         t = st["frontier"]
         with jax.named_scope("jaxsim.frontier"):
@@ -1232,33 +1209,41 @@ def _engine_fns(static: JaxSimStatic):
         with jax.named_scope("jaxsim.frontier"):
             seg_min = st["seg_min"].at[sidx].set(
                 jnp.where(has_due, seg_min_new, st["seg_min"][sidx]))
-            t_dev = jnp.min(seg_min)
-
         with jax.named_scope("jaxsim.queue"):
-            q_start, q_dev, q_samp, tail = apply_append_seg(
-                st["q_start"], st["q_dev"], st["q_samp"], st["tail"],
-                append)
-            # --- server dynamic batching: only once the instant's
-            # completions have all drained (t_dev > t), so ties across
-            # segments enqueue in full device-index order before the
-            # ladder sizes the batch -----------------------------------
-            qlen = tail - st["head"]
-            can_pop = (go & (t >= st["busy_until"]) & (qlen > 0)
-                       & (t_dev > t))
-            p = pop_calc_seg(t, q_start, q_dev, q_samp, st["head"],
-                             st["server_idx"], srv, qlen, can_pop)
-            met_srv = (p["latency"] <= c["slo"][p["devs"]]) & p["take"]
-            win_met = wb["win_met"].at[p["devs"]].add(met_srv)
-            win_total = wb["win_total"].at[p["devs"]].add(p["take"])
-            tot_met = wb["tot_met"].at[p["devs"]].add(met_srv)
-            tot = wb["tot"].at[p["devs"]].add(p["take"])
-            correct = wb["correct"].at[p["devs"]].add(
-                p["take"] * c["ch"][p["devs"], p["samps"],
-                                    st["server_idx"]])
+            slots, tail = _ring_slots(st["tail"], append["fwd"], cap)
+        return dict(st, **wb, tail=tail, last_done_t=last_done_t,
+                    seg_min=seg_min), dict(append, slots=slots)
+
+    def lane_launch(st, c, srv, go, popped):
+        """The rest of the event after the ring write: the batch launch
+        from the head entries ``popped``, the launched batch's counters,
+        and the frontier. ``st`` is ``lane_event``'s state; no-op bitwise
+        if ~go."""
+        t = st["frontier"]
+        with jax.named_scope("jaxsim.queue"):
+            # --- server dynamic batching ----------------------------------
+            qlen = st["tail"] - st["head"]
+            can_pop = (t >= st["busy_until"]) & (qlen > 0) & go
+            if G:
+                # only once the instant's completions have all drained
+                # (t_dev > t), so ties across segments enqueue in full
+                # device-index order before the ladder sizes the batch
+                with jax.named_scope("jaxsim.frontier"):
+                    t_dev = jnp.min(st["seg_min"])
+                can_pop = can_pop & (t_dev > t)
+            p = _pop_calc(t, popped, st["server_idx"], srv, qlen, can_pop)
+            devs, take = p["devs"], p["take"]
+            met_srv = (p["latency"] <= c["slo"][devs]) & take
+            win_met = st["win_met"].at[devs].add(met_srv)
+            win_total = st["win_total"].at[devs].add(take)
+            tot_met = st["tot_met"].at[devs].add(met_srv)
+            tot = st["tot"].at[devs].add(take)
+            correct = st["correct"].at[devs].add(
+                take * c["ch"][devs, p["samps"], st["server_idx"]])
             head = st["head"] + jnp.where(can_pop, p["b"], 0)
             busy_until = jnp.where(can_pop, p["finish"], st["busy_until"])
             last_batch = jnp.where(can_pop, p["b"], st["last_batch"])
-            last_done_t = jnp.where(can_pop, p["finish"], last_done_t)
+            last_done_t = jnp.where(can_pop, p["finish"], st["last_done_t"])
             max_qlen = jnp.where(go, jnp.maximum(st["max_qlen"], qlen),
                                  st["max_qlen"])
 
@@ -1266,13 +1251,12 @@ def _engine_fns(static: JaxSimStatic):
             st2 = dict(
                 st, t=jnp.where(go, t, st["t"]),
                 n_events=st["n_events"] + go,
-                dev_next=wb["dev_next"], cursor=wb["cursor"],
                 win_met=win_met, win_total=win_total, tot_met=tot_met,
-                tot=tot, correct=correct, fwd=wb["fwd"], q_start=q_start,
-                q_dev=q_dev, q_samp=q_samp, head=head, tail=tail,
-                busy_until=busy_until, last_batch=last_batch,
-                last_done_t=last_done_t, seg_min=seg_min,
+                tot=tot, correct=correct, head=head, busy_until=busy_until,
+                last_batch=last_batch, last_done_t=last_done_t,
                 max_qlen=max_qlen, k=st["k"] + go)
+            # the pre-extracted frontier: the only place it ever moves — a
+            # window boundary touches no queue/cursor/server-timing state
             st2["frontier"] = jnp.where(go, next_event_t(st2),
                                         st["frontier"])
         return st2
@@ -1387,8 +1371,8 @@ def _engine_fns(static: JaxSimStatic):
             "final_thresh": final["thresh"],
         }
 
-    return (lane_init, lane_event_seg if G else lane_event, lane_boundary,
-            lane_metrics)
+    return (lane_init, lane_event_seg if G else lane_event, lane_launch,
+            lane_boundary, lane_metrics)
 
 
 def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
@@ -1398,24 +1382,38 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
 
     The carry is one dict of B-leading arrays plus per-lane ``active``,
     ``frontier`` (next-event time), ``w`` (window) and ``k`` (events this
-    window). Each ``body`` call advances EVERY lane that has an event due
-    inside its current window by exactly that one event (per-field masked
-    writes — a held or finished lane is bitwise frozen), then runs a
-    ``lax.cond``-gated window-boundary step for lanes whose frontier
-    passed their window end. Lanes never wait for each other: the loop
-    trips are max-over-lanes of (events + windows), not
-    sum-over-windows of max-over-lanes as under vmapped while_loops.
+    window), and the queue ring: ``RING_FIELDS``, each one flat
+    ``(B * cap,)`` buffer in which lane i owns slots
+    ``[i * cap, (i + 1) * cap)``. Each ``body`` call advances EVERY lane
+    that has an event due inside its current window by exactly that one
+    event (per-field masked writes — a held or finished lane is bitwise
+    frozen), then runs a ``lax.cond``-gated window-boundary step for
+    lanes whose frontier passed their window end. Lanes never wait for
+    each other: the loop trips are max-over-lanes of (events + windows),
+    not sum-over-windows of max-over-lanes as under vmapped while_loops.
     """
-    lane_init, lane_event, lane_boundary, lane_metrics = _engine_fns(static)
-    bsz = conf.shape[0]
+    lane_init, lane_event, lane_launch, lane_boundary, lane_metrics = (
+        _engine_fns(static))
+    bsz, cap = conf.shape[0], static.cap
+    if bsz * cap >= 2 ** 31:
+        raise ValueError(f"{bsz} lanes of {cap} ring slots overflow the "
+                         "int32 ring index; split the sweep")
+    # each lane's first slot in the flat ring, (B, 1) against its rows
+    base = (jnp.arange(bsz, dtype=jnp.int32) * cap)[:, None]
     consts = dict(params, conf=conf, cl=cl, ch=ch, arrive=arrive,
                   dev_latency=dev_latency, slo=slo, tier_ids=tier_ids,
                   c_upper=c_upper, off_start=off_start, off_for=off_for,
                   join_t=join_t, leave_t=leave_t)
     init_v = jax.vmap(lane_init)
-    event_v = jax.vmap(lane_event, in_axes=(0, 0, None, 0))
+    event_v = jax.vmap(lane_event)
+    launch_v = jax.vmap(lane_launch, in_axes=(0, 0, None, 0, 0))
     boundary_v = jax.vmap(lane_boundary, in_axes=(0, 0, 0))
     metrics_v = jax.vmap(lane_metrics)
+
+    def split(st):
+        # the per-lane state the vmapped pieces see, and the flat ring
+        return ({k: v for k, v in st.items() if k not in RING_FIELDS},
+                {k: st[k] for k in RING_FIELDS})
 
     @jax.named_scope("jaxsim.frontier")
     def event_flags(st):
@@ -1427,10 +1425,18 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
                 & (st["k"] < static.max_events_per_window))
 
     def body(st):
+        st, ring = split(st)
         # one named scope per phase of a trip names its ops in a profiler
         # trace (docs/ARCHITECTURE.md, "Tracing"); no op changes
         with jax.named_scope("jaxsim.event"):
-            st = event_v(st, consts, srv, event_flags(st))
+            go = event_flags(st)
+            st, push = event_v(st, consts, go)
+            # one flat scatter and gather over all lanes, on the carried
+            # buffers (in place); the pop reads the updated ring
+            with jax.named_scope("jaxsim.queue"):
+                ring = _ring_write(ring, base, push["slots"], push, cap)
+                popped = _ring_read(ring, base, st["head"][:, None], cap)
+            st = launch_v(st, consts, srv, go, popped)
         # boundary after the event of the same iteration: a lane whose
         # frontier just left the window takes its boundary immediately
         # (same per-lane op sequence as event-then-boundary, fewer trips)
@@ -1459,12 +1465,16 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
             traces = {key: st["traces"][key].at[bidx, wj].set(
                           row[key], mode="drop")
                       for key in TRACE_KEYS}
-        return dict(st, traces=traces, **upd)
+        return dict(st, traces=traces, **upd, **ring)
 
     def finalize(st):
-        return metrics_v(st, consts)
+        return metrics_v(split(st)[0], consts)
 
-    return init_v(consts), body, finalize
+    st0 = dict(init_v(consts),
+               q_start=jnp.zeros((bsz * cap,), jnp.float32),
+               q_dev=jnp.zeros((bsz * cap,), jnp.int32),
+               q_samp=jnp.zeros((bsz * cap,), jnp.int32))
+    return st0, body, finalize
 
 
 def _run_core_lanes(static, params, srv, conf, cl, ch, arrive, dev_latency,
@@ -1501,7 +1511,7 @@ def _device_engine(static: JaxSimStatic, k: int, axis: str):
     window, cap, G = static.window, static.cap, static.seg
     n_loc = n // k
     n_segs_loc = n_loc // G
-    completion, apply_append, pop_calc = _seg_phases(static)
+    completion = _seg_phases(static)
 
     def psum(x):
         return jax.lax.psum(x, axis)
@@ -1617,15 +1627,18 @@ def _device_engine(static: JaxSimStatic, k: int, axis: str):
             ex = psum(dict(append,
                            comp_any=comp_any_loc.astype(jnp.int32)))
             comp_any = ex.pop("comp_any") > 0
-            q_start, q_dev, q_samp, tail = apply_append(
-                st["q_start"], st["q_dev"], st["q_samp"], st["tail"], ex)
+            # the one replicated ring, at base 0: every shard applies the
+            # identical write
+            slots, tail = _ring_slots(st["tail"], ex["fwd"], cap)
+            ring = _ring_write({key: st[key] for key in RING_FIELDS}, 0,
+                               slots, ex, cap)
             last_done_t = jnp.where(comp_any, t, st["last_done_t"])
 
             qlen = tail - st["head"]
             can_pop = (go & (t >= st["busy_until"]) & (qlen > 0)
                        & (t_dev > t))
-            p = pop_calc(t, q_start, q_dev, q_samp, st["head"],
-                         st["server_idx"], srv, qlen, can_pop)
+            p = _pop_calc(t, _ring_read(ring, 0, st["head"], cap),
+                          st["server_idx"], srv, qlen, can_pop)
             # popped entries' slo / heavy-correctness live on the owning
             # shards: masked local gathers, one psum to replicate
             ldev = p["devs"] - off
@@ -1661,9 +1674,8 @@ def _device_engine(static: JaxSimStatic, k: int, axis: str):
                 n_events=st["n_events"] + go,
                 dev_next=wb["dev_next"], cursor=wb["cursor"],
                 win_met=win_met, win_total=win_total, tot_met=tot_met,
-                tot=tot, correct=correct, fwd=wb["fwd"], q_start=q_start,
-                q_dev=q_dev, q_samp=q_samp, head=head, tail=tail,
-                busy_until=busy_until, last_batch=last_batch,
+                tot=tot, correct=correct, fwd=wb["fwd"], **ring, head=head,
+                tail=tail, busy_until=busy_until, last_batch=last_batch,
                 last_done_t=last_done_t, seg_min=seg_min,
                 max_qlen=max_qlen, k=st["k"] + go)
             qlen2 = tail - head
@@ -2006,7 +2018,9 @@ def lane_stepper(specs, streams, dev_latency, slo,
 
     Returns ``(state, step, static)``: ``state`` is the flat (B, ...)
     carry dict (per-lane ``active``/``frontier``/``w``/``k`` plus the
-    per-device state vectors), ``step`` maps carry -> carry for one
+    per-device state vectors; the ``RING_FIELDS`` are flat
+    ``(B * static.cap,)`` buffers, lane i's ring at slots
+    ``[i * cap, (i + 1) * cap)``), ``step`` maps carry -> carry for one
     loop iteration, and ``static`` is the ``JaxSimStatic`` recompile
     key; ``jnp.any(state["active"])`` is the loop condition the core
     uses.

@@ -336,8 +336,13 @@ def test_b1_rides_the_same_core():
 # via jaxsim.lane_stepper (hypothesis when installed, the conftest mini
 # engine otherwise)
 # ---------------------------------------------------------------------------
-def _lane_view(state, i):
-    return jax.tree.map(lambda x: np.asarray(x)[i], state)
+def _lane_view(state, i, cap):
+    # the queue ring is carried flat over the lanes, lane i owning slots
+    # [i * cap, (i + 1) * cap): view it as (B, cap) so a held lane's
+    # whole ring is checked, not one slot
+    view = {k: np.asarray(v).reshape(-1, cap) if k in jaxsim.RING_FIELDS
+            else v for k, v in state.items()}
+    return jax.tree.map(lambda x: np.asarray(x)[i], view)
 
 
 def _frozen(a, b):
@@ -365,7 +370,7 @@ def _drive_and_check(cases, samples=12, max_iters=8000):
         assert np.all(w[act] < static.n_windows)
         for i in range(b):
             if not act[i] and prev_views[i] is None:
-                prev_views[i] = _lane_view(state, i)
+                prev_views[i] = _lane_view(state, i, static.cap)
         state = step(state)
         frontier = np.asarray(state["frontier"])
         # frontier is non-decreasing per lane (an event advances it, a
@@ -376,7 +381,8 @@ def _drive_and_check(cases, samples=12, max_iters=8000):
         # a lane that went inactive is bitwise frozen ever after
         for i in range(b):
             if prev_views[i] is not None:
-                assert _frozen(prev_views[i], _lane_view(state, i)), \
+                assert _frozen(prev_views[i],
+                               _lane_view(state, i, static.cap)), \
                     f"inactive lane {i} mutated"
         iters += 1
     # any(active) False implies every lane drained: all real samples

@@ -3,6 +3,7 @@ stream generation, static/traced recompile behaviour."""
 import compile_guard
 import numpy as np
 import pytest
+from lane_utils import assert_lane_bitwise
 
 from repro.configs.cascade_tiers import DEVICE_PROFILES, SERVER_PROFILES
 from repro.sim import jaxsim, synthetic
@@ -72,6 +73,45 @@ def test_sweep_matches_serial_bitwise(sched):
         np.testing.assert_array_equal(
             np.asarray(serial["per_device_sr"]),
             np.asarray(sweep["per_device_sr"][i]))
+
+
+# lanes of one sweep that differ in scheduler and device count
+RING_LANES = (("multitasc++", 8), ("multitasc", 5), ("static", 3),
+              ("multitasc++", 6))
+
+
+@pytest.mark.parametrize("queue_cap,frontier_seg", [
+    (None, False),     # the default ring: no lane wraps
+    (65, False),       # every lane wraps its ring two to six times
+    (65, True),        # the same through the segmented frontier
+])
+def test_sweep_ring_lanes_match_serial_bitwise(queue_cap, frontier_seg):
+    """The queue ring is one flat buffer over the lanes: a lane's
+    appends, wraps and pops stay inside its own slots, so every lane of
+    a mixed sweep equals its own serial run bitwise."""
+    lat, slo = _args()
+    specs = [jaxsim.JaxSimSpec(scheduler=sched, n_devices=n,
+                               samples_per_device=SAMPLES,
+                               init_threshold=0.9, static_threshold=0.9,
+                               queue_cap=queue_cap)
+             for sched, n in RING_LANES]
+    seeds = tuple(range(len(specs)))
+    batched = synthetic.batched_device_streams(seeds, N, SAMPLES,
+                                               DP.accuracy, SP.accuracy)
+    sweep = jaxsim.run_sweep(specs, batched, lat, slo, (SP,),
+                             frontier_seg=frontier_seg)
+    for i, spec in enumerate(specs):
+        n = spec.n_devices
+        streams = {k: v[i, :n] for k, v in batched.items()}
+        serial = jaxsim.run(spec, streams, lat[:n], slo[:n], (SP,),
+                            frontier_seg=frontier_seg)
+        assert_lane_bitwise(sweep, i, serial, n)
+        if queue_cap is not None:
+            # no lane overruns its ring, and every lane wraps it
+            forwarded = float(sweep["forwarded_frac"][i]) \
+                * int(sweep["completed"][i])
+            assert int(sweep["queue_peak"][i]) < queue_cap
+            assert forwarded > 2 * queue_cap, (i, forwarded)
 
 
 def test_one_compile_serves_many_traced_scalars():

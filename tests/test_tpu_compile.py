@@ -14,7 +14,8 @@ Covered:
 * ``classify_fn``'s forward for ``tier-server-heavy`` at the top bucket
   under ``pallas`` dispatch;
 * the lane-aligned sim core at fig11_lanes' B=64, and at N=4096, where
-  the segmented frontier is on;
+  the segmented frontier is on — and that its event loop never copies
+  the server queue ring (no ring-sized relayout in the while body);
 * the device-axis-sharded sim core on the four-chip mesh;
 * the sim cores' phase scopes (``jaxsim.*``), which must reach the ops'
   metadata the TPU profiler records.
@@ -24,6 +25,7 @@ only one process may load the TPU library, and several test workers
 import this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +93,49 @@ def _assert_scoped(compiled):
         assert scope in text, scope
 
 
+# ops that move a whole buffer to a new shape or layout; the TPU
+# compiler lowers a one-element dimension's removal as a ``reduce``
+RELAYOUT_OPS = ("reshape", "copy", "transpose", "reduce")
+
+
+def _while_body(text):
+    """The instruction lines of the compiled while loop's body and of
+    every computation it calls."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            lines = comps.setdefault(head.group(1), [])
+        elif line == "}":
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    todo = re.findall(r"\bbody=%([^\s,]+)", text)
+    assert todo, "no while loop in the compiled core"
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += [ref for line in comps[name]
+                     for ref in re.findall(r"%([\w.\-]+)", line)
+                     if ref in comps]
+    return [line for name in seen for line in comps[name]]
+
+
+def _assert_ring_not_copied(compiled, b, cap):
+    """The event loop updates the queue ring in place: no op in its
+    body relayouts a ring-sized buffer (``b * cap`` elements, or
+    ``[b, cap]``), as the copies in and out of a flat layout around a
+    vmapped ring write did on every trip."""
+    ring = re.compile(rf"\[(?:{b * cap}|{b},{cap})\]")
+    op = re.compile(r"= \S+ ({})\(".format("|".join(RELAYOUT_OPS)))
+    copies = [line.strip()[:160] for line in _while_body(compiled.as_text())
+              if ring.search(line.split(" = ", 1)[-1].split("(", 1)[0])
+              and op.search(line)]
+    assert not copies, copies
+
+
 def _assert_fits(compiled):
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -149,6 +194,7 @@ def test_lane_core_compiles(one_chip, seeds, n, samples, segmented):
                           *_shapes(arrays, one_chip)).compile()
     _assert_fits(compiled)
     _assert_scoped(compiled)
+    _assert_ring_not_copied(compiled, len(seeds), static.cap)
 
 
 def test_device_sharded_core_compiles(topo):

@@ -256,12 +256,12 @@ benchmarks/fig_scale.py, pinned by tests/test_scale.py):
   accepted anywhere a stream dict is — generation peaks at O(chunk)
   host memory, bitwise equal to the dense fixture-v2 tensors.
 * ``run_device_sharded(..., mesh=make_sweep_mesh((k,)))``: shards ONE
-  fleet's device axis (and segment mins) over the mesh; per event two
-  ``pmin``s elect the frontier/owner shard and ``psum``s exchange
-  O(G + MAX_POP)-sized buffers only. Fleet dynamics are bitwise equal
-  to the local segmented run; float aggregates built from per-shard
-  partial sums (``accuracy``, trace thresh/sr/acc) may differ in the
-  last ulp (psum reduction order).
+  fleet's device axis (and segment mins) over the mesh; per event three
+  ``pmin``s elect the frontier/owner shard and the completion frontier,
+  and ``psum``s exchange O(G + MAX_POP)-sized buffers only. Fleet
+  dynamics are bitwise equal to the local segmented run; float
+  aggregates built from per-shard partial sums (``accuracy``, trace
+  thresh/sr/acc) may differ in the last ulp (psum reduction order).
 
 ``JaxSimSpec.queue_cap`` bounds the server ring (must exceed
 ``MAX_POP``): each lane's ``cap`` slots of the lane core's flat ring
@@ -279,6 +279,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import jaxpr_tools
 from repro.configs.cascade_tiers import BATCH_LADDER, ServerProfile
 from repro.core import multitasc as mt
 from repro.core import multitascpp as mtpp
@@ -369,6 +370,11 @@ class SweepStats:
     events: int = 0             # event-loop iterations across all points
     sharded_points: int = 0     # points executed by a >1-lane sharded core
     device_sharded_points: int = 0  # points run with the DEVICE axis sharded
+    # collectives (psum/pmin) one trip of the device-sharded core issues,
+    # counted from its jaxpr (``_exchanges_per_trip``), and the trips its
+    # calls made (summed ``n_events``)
+    device_sharded_exchanges_per_trip: int = 0
+    device_sharded_trips: int = 0
 
 
 stats = SweepStats()
@@ -1495,17 +1501,20 @@ def _device_engine(static: JaxSimStatic, k: int, axis: str):
     every shard applies the identical update to it. The per-event
     arithmetic is ``_seg_phases`` — the same closures the local
     segmented lane runs — with a small fixed set of collectives spliced
-    between the phases (frontier pmin + owner-segment pmin, a G-wide
-    append psum, a MAX_POP-wide gather psum, and two boundary partial-
-    sum psums on window-closing iterations). All collective operands are
-    O(G + MAX_POP + MAX_TIERS), independent of fleet size. The fleet's
-    *dynamics* (thresholds, queue contents, switching, event order) are
-    bitwise identical to the local segmented engine's: every quantity
-    that feeds back into state is an exact integer sum or an elementwise
-    float op. Only reported float *aggregates* (trace-row means, the
-    ``accuracy`` metric) may differ in the last ulp, because a psum of
-    per-shard partial sums associates float additions differently than
-    one flat ``jnp.sum``.
+    between the phases (frontier pmin + owner-segment pmin + completion-
+    frontier pmin, a G-wide append psum, a MAX_POP-wide gather psum, and
+    two boundary partial-sum psums). Every trip issues all of them: the
+    boundary psums run on every trip too, with zeros where no window
+    closes, because a collective may not sit inside a ``lax.cond``
+    under ``shard_map``. They carry the ``jaxsim.exchange`` scope. All
+    collective operands are O(G + MAX_POP + MAX_TIERS), independent of
+    fleet size. The fleet's *dynamics* (thresholds, queue contents,
+    switching, event order) are bitwise identical to the local segmented
+    engine's: every quantity that feeds back into state is an exact
+    integer sum or an elementwise float op. Only reported float
+    *aggregates* (trace-row means, the ``accuracy`` metric) may differ
+    in the last ulp, because a psum of per-shard partial sums associates
+    float additions differently than one flat ``jnp.sum``.
     """
     n, s = static.n_pad, static.samples_per_device
     window, cap, G = static.window, static.cap, static.seg
@@ -1513,9 +1522,11 @@ def _device_engine(static: JaxSimStatic, k: int, axis: str):
     n_segs_loc = n_loc // G
     completion = _seg_phases(static)
 
+    @jax.named_scope("jaxsim.exchange")
     def psum(x):
         return jax.lax.psum(x, axis)
 
+    @jax.named_scope("jaxsim.exchange")
     def pmin(x):
         return jax.lax.pmin(x, axis)
 
@@ -1939,6 +1950,21 @@ def _make_core_device(static: JaxSimStatic, mesh):
     return jax.jit(sharded, donate_argnums=(2, 3, 4, 5))
 
 
+@functools.lru_cache(maxsize=64)
+def _exchanges_per_trip(jaxpr) -> int:
+    """Collectives one trip of the device-sharded core issues, read from
+    its ClosedJaxpr: the ``psum`` and ``pmin`` equations of its while
+    loop's body, with those
+    of the branches and calls inside it. JAX binds one equation per
+    array of a collective's operand; the compiler may combine
+    independent ones into fewer all-reduces."""
+    body = next(eqn.params["body_jaxpr"].jaxpr
+                for _, eqn in jaxpr_tools.walk_eqns(jaxpr.jaxpr)
+                if eqn.primitive.name == "while")
+    return sum(eqn.primitive.name in ("psum", "pmin")
+               for _, eqn in jaxpr_tools.walk_eqns(body))
+
+
 def run_device_sharded(spec: JaxSimSpec, streams, dev_latency, slo,
                        servers: Sequence[ServerProfile], *, mesh=None,
                        tier_ids=None, c_upper=None, offline_start=None,
@@ -1993,13 +2019,17 @@ def run_device_sharded(spec: JaxSimSpec, streams, dev_latency, slo,
              *(jax.device_put(a, rep_sh if i == 7 else dev_sh)
                for i, a in enumerate(arrays1))))
     with _host_span("jaxsim.execute"):
-        out = dict(_make_core_device(static, mesh)(*args))
+        core = _make_core_device(static, mesh)
+        exchanges = _exchanges_per_trip(core.trace(*args).jaxpr)
+        out = dict(core(*args))
         for key in _DEVICE_OUT_SHARDED:
             out[key] = np.asarray(out[key])[:n]
         out["n_events"] = np.asarray(out["n_events"])
     stats.points += 1
     stats.events += int(out["n_events"])
     stats.device_sharded_points += 1
+    stats.device_sharded_exchanges_per_trip = exchanges
+    stats.device_sharded_trips += int(out["n_events"])
     return out
 
 

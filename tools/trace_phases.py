@@ -10,11 +10,12 @@ times one unit untraced, then traces one unit (its first
 ``trace_seconds`` where the traffic sets them; the unit runs on untraced)
 and reduces the trace with ``perfbench/core/scopes.py``. It prints one
 JSON object: the device time per trip of each phase scope of the sim
-core and of the ops in none, the loop's period, device idle time by host
-span, the unit walls traced and untraced, and the reductions' own
-times. ``--keep`` copies the trace's ``.xplane.pb`` there. The device
-numbers need a TPU: elsewhere the trace has no device plane and they
-read null.
+core (``jaxsim.exchange`` holds the device-sharded core's collectives)
+and of the ops in none, the cell's per-layer metrics as the benchmark's
+readers read them from the same trace, device idle time by host span,
+the unit walls traced and untraced, and the reductions' own times.
+``--keep`` copies the trace's ``.xplane.pb`` there. The device numbers
+need a TPU: elsewhere the trace has no device plane and they read null.
 """
 from __future__ import annotations
 
@@ -36,6 +37,8 @@ from perfbench.core import scopes, spec, trace  # noqa: E402
 
 PHASES = ("jaxsim.devices", "jaxsim.queue", "jaxsim.frontier",
           "jaxsim.boundary")
+# the device-sharded core's collectives (none in the lane cores)
+EXCHANGE = "jaxsim.exchange"
 SPANS = ("jaxsim.prepare", "jaxsim.transfer", "jaxsim.execute")
 # readers of the benchmark's per-layer metrics that read the trace alone
 EXISTING = ("core_us_per_iter.sim", "sim.host_ms_per_sweep",
@@ -44,16 +47,17 @@ EXISTING = ("core_us_per_iter.sim", "sim.host_ms_per_sweep",
 
 def traced_unit(jax, drv, seconds, logdir):
     """One unit under the profiler, in a ``bench.window`` span that closes
-    when the unit ends or after ``seconds``; returns the unit's wall."""
+    when the unit ends or after ``seconds``; returns the unit's wall and
+    the unit."""
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
-    wall, failure = [], []
+    wall, units, failure = [], [], []
 
     def work():
         try:
             t0 = time.perf_counter()
-            drv.unit()
+            units.append(drv.unit())
             wall.append(time.perf_counter() - t0)
         except BaseException as e:  # re-raised by the caller's thread
             failure.append(e)
@@ -68,16 +72,20 @@ def traced_unit(jax, drv, seconds, logdir):
     worker.join()
     if failure:
         raise failure[0]
-    return wall[0]
+    return wall[0], units[0]
 
 
-def reduce(tr) -> dict:
+def reduce(tr, metrics=EXISTING, units=()) -> dict:
+    """The phase split of trace ``tr``, with the per-layer metrics
+    ``metrics`` read from it by the benchmark's own readers (``units``:
+    the traced units, for readers that read them)."""
     trips = scopes.event_trips(tr)
     per_trip = {s: scopes.scope_us_per_trip(tr, s)
-                for s in PHASES + (scopes.EVENT_SCOPE, scopes.UNSCOPED)}
+                for s in PHASES + (EXCHANGE, scopes.EVENT_SCOPE,
+                                   scopes.UNSCOPED)}
     busy = trace.busy_s(tr)
-    phases = [per_trip[s] for s in PHASES]
-    ctx = types.SimpleNamespace(trace=tr)
+    phases = [per_trip[s] for s in PHASES + (EXCHANGE,)]
+    ctx = types.SimpleNamespace(trace=tr, units=list(units))
     return {
         "trips": trips,
         "us_per_trip": per_trip,
@@ -85,7 +93,7 @@ def reduce(tr) -> dict:
                                else None),
         "busy_us_per_trip": busy * 1e6 / trips if trips else None,
         # the benchmark's per-layer metrics, read by its own readers
-        "metrics": {m: spec.reader(m)(ctx) for m in EXISTING},
+        "metrics": {m: spec.reader(m)(ctx) for m in metrics},
         "busy_s": busy, "window_s": tr.window_s,
         "idle_s_by_span": scopes.idle_s_by_span(tr),
         "idle_ms": {s: scopes.idle_ms_by_span(tr, s) for s in SPANS},
@@ -124,8 +132,8 @@ def main(argv=None) -> int:
 
     logdir = tempfile.mkdtemp(prefix="trace-phases-")
     try:
-        traced_wall = traced_unit(jax, drv, traffic.get("trace_seconds"),
-                                  logdir)
+        traced_wall, unit = traced_unit(
+            jax, drv, traffic.get("trace_seconds"), logdir)
         path = trace.find_xplane(logdir)
         if args.keep:
             shutil.copyfile(path, args.keep)
@@ -136,7 +144,8 @@ def main(argv=None) -> int:
         tr = scopes.load(path)
         scopes_load_s = time.perf_counter() - t0
         t0 = time.perf_counter()
-        result = reduce(tr)
+        result = reduce(tr, [m["name"] for m in spec.per_layer(
+            bench, args.workload)], [unit])
         result["reduce_s"] = time.perf_counter() - t0
         result["xplane_bytes"] = os.path.getsize(path)
     finally:

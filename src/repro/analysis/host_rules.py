@@ -68,7 +68,8 @@ CACHED_FACTORY_DECORATORS = {"lru_cache", "cache"}
 # both the compiled core and the jitted host wrappers close over
 TRACED_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/sim/jaxsim.py": {
-        "_ring_slots", "_ring_write", "_ring_read", "_pop_calc",
+        "_ring_slots", "_ring_write", "_ring_read", "_pack_stream_words",
+        "_unpack_stream_words", "_pop_calc",
         "_seg_phases", "_engine_fns", "_batched_engine",
         "_run_core_lanes", "_device_engine", "_run_core_device",
     },

@@ -498,7 +498,9 @@ def run(spec: JaxSimSpec, streams, dev_latency, slo, servers:
       spec: the point's ``JaxSimSpec`` (scheduler, fleet size, gains).
       streams: dict of per-device sample tensors —
         ``confidence`` (N, S) float in [0, 1], ``correct_light`` (N, S)
-        {0, 1}, ``correct_heavy`` (N, S, P) {0, 1} with one column per
+        {0, 1} (a confidence below 0 or NaN, or a ``correct_light``
+        outside {0, 1}, raises ``ValueError``; a -0.0 runs as +0.0),
+        ``correct_heavy`` (N, S, P) {0, 1} with one column per
         server profile (a (N, S) array is treated as P=1), and optional
         ``arrive`` (N, S): cumulative arrival time of each sample in
         seconds (omitted/zeros = the saturated legacy model). Generate
@@ -562,6 +564,14 @@ def _prepare(specs, streams, dev_latency, slo, servers, tier_ids, c_upper,
     ch = np.asarray(streams["correct_heavy"], np.int32)
     arrive = streams.get("arrive")
     arrive = None if arrive is None else np.asarray(arrive, np.float32)
+    # the flat lane core packs a sample's confidence and light-model
+    # correctness into one 32-bit word (_pack_stream_words): the sign
+    # bit carries correct_light, so a confidence must be >= 0 (NaN
+    # fails the test too) and correct_light 0 or 1
+    if not np.all(conf >= 0):
+        raise ValueError("streams['confidence'] must be >= 0 and not NaN")
+    if cl.size and (cl.min() < 0 or cl.max() > 1):
+        raise ValueError("streams['correct_light'] must be 0 or 1")
     if conf.ndim == 2:
         conf, cl, ch = conf[None], cl[None], ch[None]
     if arrive is not None and arrive.ndim == 2:
@@ -739,7 +749,12 @@ def run_sweep(specs: Union[JaxSimSpec, Sequence[JaxSimSpec]], streams,
     (``sr``: (B,), ``traces[key]``: (B, n_windows), ...). All traced
     values — including churn schedules and arrival tensors — vary freely
     across points without recompiling; only static structure forces a
-    new executable. Stream buffers are donated to the computation.
+    new executable. Stream buffers are donated to the computation. With
+    the flat frontier the core packs ``confidence`` and
+    ``correct_light`` once a call into one flat lane-major buffer of
+    32-bit words, lane b, device i, sample j at ``(b * n_pad + i) * S +
+    j`` (docs/ARCHITECTURE.md, "The lane-aligned batched loop"); the
+    input contract is ``run``'s.
     """
     with _host_span("jaxsim.prepare"):
         static, params, srv, arrays, b, n = _prepare(
@@ -907,6 +922,31 @@ def _ring_read(ring, base, head, cap):
     appends (a batch may take same-event entries)."""
     idx = base + (head + jnp.arange(MAX_POP, dtype=jnp.int32)) % cap
     return {key: ring[key][idx] for key in RING_FIELDS}
+
+
+# The flat lane core's sample streams: one 32-bit word per sample, the
+# confidence's float32 bits with the light model's correctness in the
+# sign bit, laid flat and lane-major (lane b, device i, sample j at
+# ``(b * n_pad + i) * s + j``). An event reads each device's sample with
+# one 1-D gather of one word: a 3-D gather per stream cost the TPU
+# about 22 ns an index, and the compiler splits a gather of a
+# two-element slice into two scalar gathers. ``_prepare`` holds the
+# confidence to >= 0, so its sign bit is free; a -0.0 packs as +0.0,
+# which compares the same.
+_CONF_BITS = 0x7FFFFFFF     # a word's bits below the sign bit
+
+
+def _pack_stream_words(conf, cl):
+    bits = jax.lax.bitcast_convert_type(conf, jnp.uint32)
+    return ((bits & jnp.uint32(_CONF_BITS))
+            | (cl.astype(jnp.uint32) << jnp.uint32(31)))
+
+
+def _unpack_stream_words(word):
+    """``(confidence, correct_light)`` of packed stream words."""
+    conf = jax.lax.bitcast_convert_type(word & jnp.uint32(_CONF_BITS),
+                                        jnp.float32)
+    return conf, (word >> jnp.uint32(31)).astype(jnp.int32)
 
 
 def _pop_calc(t, popped, server_idx, srv, qlen, can_pop):
@@ -1107,13 +1147,17 @@ def _engine_fns(static: JaxSimStatic):
                                       jnp.float32) for key in TRACE_KEYS}
         return st
 
+    def stream_read(c, key, j):
+        # each device's sample j from a flat lane-major stream buffer
+        # (``_batched_engine``), shared by every lane: one 1-D gather
+        row = c["lane"] * n + jnp.arange(n, dtype=jnp.int32)
+        return c[key][row * s + j]
+
     def lane_event(st, c, go):
         """The device completions of one lane's frontier event; no-op
         bitwise if ~go. Returns the updated state (the tail past the
         appends; head, clock and frontier not yet moved) and the ring
         append: ``RING_FIELDS`` rows plus their ``slots``."""
-        conf, cl = c["conf"], c["cl"]
-        arrive_c = c["arrive"]
         dev_latency, slo = c["dev_latency"], c["slo"]
         t = st["frontier"]
 
@@ -1127,7 +1171,8 @@ def _engine_fns(static: JaxSimStatic):
             departs = due & (st["dev_next"] >= c["leave_t"])
             done = due & ~departs
             cj = jnp.clip(st["cursor"], 0, s - 1)
-            conf_j = conf[jnp.arange(n, dtype=jnp.int32), cj]
+            conf_j, cl_j = _unpack_stream_words(
+                stream_read(c, "stream_word", cj))
             local = conf_j >= st["thresh"]          # Eq. 3
             comp_local = done & local
             met_local = dev_latency <= slo
@@ -1135,8 +1180,7 @@ def _engine_fns(static: JaxSimStatic):
             win_total = st["win_total"] + comp_local
             tot_met = st["tot_met"] + (comp_local & met_local)
             tot = st["tot"] + comp_local
-            correct = st["correct"] + comp_local * cl[
-                jnp.arange(n, dtype=jnp.int32), cj]
+            correct = st["correct"] + comp_local * cl_j
             fwd_mask = done & ~local
             st_fwd = st["fwd"] + fwd_mask
 
@@ -1148,8 +1192,8 @@ def _engine_fns(static: JaxSimStatic):
             # arrived (no arrival tensor -> back-to-back, the gather
             # compiles out)
             if static.has_arrive:
-                arrive_next = arrive_c[jnp.arange(n, dtype=jnp.int32),
-                                       jnp.clip(cursor, 0, s - 1)]
+                arrive_next = stream_read(c, "stream_arrive",
+                                          jnp.clip(cursor, 0, s - 1))
                 start_next = jnp.maximum(st["dev_next"], arrive_next)
             else:
                 start_next = st["dev_next"]
@@ -1397,6 +1441,12 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
     lanes whose frontier passed their window end. Lanes never wait for
     each other: the loop trips are max-over-lanes of (events + windows),
     not sum-over-windows of max-over-lanes as under vmapped while_loops.
+
+    With the flat frontier (``static.seg == 0``) the streams enter the
+    loop packed, once a call, as flat lane-major buffers that every lane
+    reads by its ``lane`` id (``_pack_stream_words``): ``stream_word``,
+    and ``stream_arrive`` with an arrival tensor. The segmented engine
+    reads G-wide slices of the (B, N, S) streams.
     """
     lane_init, lane_event, lane_launch, lane_boundary, lane_metrics = (
         _engine_fns(static))
@@ -1406,15 +1456,34 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
                          "int32 ring index; split the sweep")
     # each lane's first slot in the flat ring, (B, 1) against its rows
     base = (jnp.arange(bsz, dtype=jnp.int32) * cap)[:, None]
-    consts = dict(params, conf=conf, cl=cl, ch=ch, arrive=arrive,
-                  dev_latency=dev_latency, slo=slo, tier_ids=tier_ids,
-                  c_upper=c_upper, off_start=off_start, off_for=off_for,
-                  join_t=join_t, leave_t=leave_t)
-    init_v = jax.vmap(lane_init)
-    event_v = jax.vmap(lane_event)
-    launch_v = jax.vmap(lane_launch, in_axes=(0, 0, None, 0, 0))
-    boundary_v = jax.vmap(lane_boundary, in_axes=(0, 0, 0))
-    metrics_v = jax.vmap(lane_metrics)
+    consts = dict(params, ch=ch, dev_latency=dev_latency, slo=slo,
+                  tier_ids=tier_ids, c_upper=c_upper, off_start=off_start,
+                  off_for=off_for, join_t=join_t, leave_t=leave_t)
+    if static.seg:
+        consts.update(conf=conf, cl=cl, arrive=arrive)
+        init_consts = consts
+    else:
+        if conf.size >= 2 ** 31:
+            raise ValueError(f"{bsz} lanes of {conf.shape[1]} x "
+                             f"{conf.shape[2]} samples overflow the int32 "
+                             "stream index; split the sweep")
+        consts.update(lane=jnp.arange(bsz, dtype=jnp.int32),
+                      stream_word=_pack_stream_words(conf, cl).reshape(-1))
+        if static.has_arrive:
+            consts["stream_arrive"] = arrive.reshape(-1)
+        # the first sample's arrival, read once before the loop
+        init_consts = dict(consts, arrive=arrive)
+
+    def axes(c):
+        # the flat stream buffers are shared by every lane, not batched
+        return {k: None if k.startswith("stream_") else 0 for k in c}
+
+    c_axes = axes(consts)
+    init_v = jax.vmap(lane_init, in_axes=(axes(init_consts),))
+    event_v = jax.vmap(lane_event, in_axes=(0, c_axes, 0))
+    launch_v = jax.vmap(lane_launch, in_axes=(0, c_axes, None, 0, 0))
+    boundary_v = jax.vmap(lane_boundary, in_axes=(0, c_axes, 0))
+    metrics_v = jax.vmap(lane_metrics, in_axes=(0, c_axes))
 
     def split(st):
         # the per-lane state the vmapped pieces see, and the flat ring
@@ -1476,7 +1545,7 @@ def _batched_engine(static, params, srv, conf, cl, ch, arrive, dev_latency,
     def finalize(st):
         return metrics_v(split(st)[0], consts)
 
-    st0 = dict(init_v(consts),
+    st0 = dict(init_v(init_consts),
                q_start=jnp.zeros((bsz * cap,), jnp.float32),
                q_dev=jnp.zeros((bsz * cap,), jnp.int32),
                q_samp=jnp.zeros((bsz * cap,), jnp.int32))

@@ -1,6 +1,7 @@
 """Batched sweep engine tests: run_sweep == serial run (bitwise), batched
 stream generation, static/traced recompile behaviour."""
 import compile_guard
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from lane_utils import assert_lane_bitwise
@@ -174,3 +175,57 @@ def test_heterogeneous_specs_batch_in_one_call():
         serial = jaxsim.run(spec, streams, lat, slo, (SP,))
         np.testing.assert_array_equal(np.asarray(serial["final_thresh"]),
                                       final[i])
+
+
+# the flat lane core reads a sample as one packed word: the confidence's
+# float32 bits with correct_light in the sign bit
+PACKED_CONF = (0.0, -0.0, float(np.finfo(np.float32).smallest_subnormal),
+               0.5, float(np.nextafter(np.float32(1), np.float32(0))), 1.0)
+
+
+@pytest.mark.parametrize("cl", [0, 1])
+@pytest.mark.parametrize("conf", PACKED_CONF)
+def test_stream_word_round_trips_bitwise(conf, cl):
+    word = jaxsim._pack_stream_words(jnp.float32(conf), jnp.int32(cl))
+    conf2, cl2 = jaxsim._unpack_stream_words(word)
+    # -0.0 comes back as +0.0, every other confidence as itself
+    expect = np.float32(conf) + np.float32(0.0)
+    assert np.asarray(conf2).view(np.uint32) == expect.view(np.uint32)
+    assert int(cl2) == cl
+
+
+@pytest.mark.parametrize("sched", ["static", "multitasc++"])
+def test_negative_zero_confidence_runs_as_zero(sched):
+    """Every confidence -0.0 runs bitwise as +0.0: under a static
+    threshold of 0 every sample stays local and its correct_light
+    counts, so a sign bit leaking into it would move the accuracy."""
+    lat, slo = _args()
+    spec = jaxsim.JaxSimSpec(scheduler=sched, n_devices=N,
+                             samples_per_device=SAMPLES,
+                             static_threshold=0.0)
+    streams = synthetic.device_streams(N, SAMPLES, DP.accuracy,
+                                       SP.accuracy, 0)
+    pos = dict(streams, confidence=np.zeros((N, SAMPLES), np.float32))
+    neg = dict(streams, confidence=np.full((N, SAMPLES), -0.0, np.float32))
+    out_pos = jaxsim.run(spec, pos, lat, slo, (SP,))
+    out_neg = jaxsim.run(spec, neg, lat, slo, (SP,))
+    assert float(out_pos["completed"]) == N * SAMPLES
+    for k in ("sr", "accuracy", "throughput", "forwarded_frac",
+              "completed", "n_events", "per_device_acc", "final_thresh"):
+        np.testing.assert_array_equal(np.asarray(out_pos[k]),
+                                      np.asarray(out_neg[k]), err_msg=k)
+
+
+@pytest.mark.parametrize("key,value", [
+    ("confidence", -0.25), ("confidence", np.nan), ("correct_light", 2)])
+def test_sweep_refuses_streams_outside_contract(key, value):
+    lat, slo = _args()
+    spec = jaxsim.JaxSimSpec(scheduler="multitasc++", n_devices=N,
+                             samples_per_device=SAMPLES)
+    streams = synthetic.batched_device_streams(SEEDS, N, SAMPLES,
+                                               DP.accuracy, SP.accuracy)
+    bad = np.array(streams[key])
+    bad[1, 3, 7] = value
+    with pytest.raises(ValueError, match=key):
+        jaxsim.run_sweep(spec, dict(streams, **{key: bad}), lat, slo,
+                         (SP,))

@@ -15,7 +15,9 @@ Covered:
   under ``pallas`` dispatch;
 * the lane-aligned sim core at fig11_lanes' B=64, and at N=4096, where
   the segmented frontier is on — and that its event loop never copies
-  the server queue ring (no ring-sized relayout in the while body);
+  the server queue ring (no ring-sized relayout in the while body), and
+  with the flat frontier reads its streams from one flat packed buffer
+  (no 3-D stream gather, no stream-sized relayout);
 * the device-axis-sharded sim core on the four-chip mesh;
 * the sim cores' phase scopes (``jaxsim.*``), which must reach the ops'
   metadata the TPU profiler records.
@@ -136,6 +138,40 @@ def _assert_ring_not_copied(compiled, b, cap):
     assert not copies, copies
 
 
+# an instruction line: its name, result shape (not a tuple), opcode and
+# operands
+HLO_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                       r"([\w\-]+)\(([^)]*)\)")
+
+
+def _assert_streams_read_flat(compiled, b, n_pad, s):
+    """The flat lane event reads each completing device's sample with
+    one gather from a flat buffer of packed words: no gather in the loop
+    body has a ``[b, n_pad, s]`` stream operand, and no op in it
+    relayouts a stream-sized buffer (``b * n_pad * s`` elements)."""
+    instrs = [m.groups() for m in map(HLO_INSTR.match,
+                                      _while_body(compiled.as_text()))
+              if m]
+    shapes = {name: tuple(int(d) for d in dims.split(",") if d)
+              for name, dims, _, _ in instrs}
+    size = b * n_pad * s
+    gathers, copies, flat_reads = [], [], 0
+    for name, _, op, args in instrs:
+        args = re.findall(r"%([\w.\-]+)", args)
+        touched = [shapes[name]] + [shapes[a] for a in args if a in shapes]
+        if op == "gather" and args:
+            operand = shapes.get(args[0])
+            if operand == (b, n_pad, s):
+                gathers.append(name)
+            flat_reads += operand == (size,)
+        if op in RELAYOUT_OPS and any(
+                int(np.prod(shape)) == size for shape in touched):
+            copies.append(name)
+    assert not gathers, gathers
+    assert not copies, copies
+    assert flat_reads == 1, flat_reads
+
+
 def _assert_fits(compiled):
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -195,6 +231,9 @@ def test_lane_core_compiles(one_chip, seeds, n, samples, segmented):
     _assert_fits(compiled)
     _assert_scoped(compiled)
     _assert_ring_not_copied(compiled, len(seeds), static.cap)
+    if not segmented:
+        _assert_streams_read_flat(compiled, len(seeds), static.n_pad,
+                                  samples)
 
 
 def test_device_sharded_core_compiles(topo):
